@@ -447,6 +447,8 @@ def run_pipeline(csv: Csv, n_db: int = 5000, n_queries: int = 64,
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=5000)
     ap.add_argument("--q", type=int, default=64)

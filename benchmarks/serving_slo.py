@@ -238,6 +238,8 @@ def record_trajectory(recs: List[Dict], commit: str, date: str,
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=2000, help="db size")
     ap.add_argument("--backend", default="numpy")

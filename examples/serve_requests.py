@@ -11,6 +11,7 @@ batched.
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.core.search import MSQIndex
 from repro.graphs.generators import aids_like_db, perturb_graph
@@ -22,6 +23,7 @@ from repro.serve import (AsyncGraphQueryEngine, GraphQuery,
 
 
 def main() -> None:
+    enable_compile_cache()
     # retrieval side: molecule database + index + pipelined query engine,
     # with per-query span recording on (DESIGN.md §17)
     db = aids_like_db(1000, seed=2)

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
-from typing import Any, Callable, Dict, Hashable, Tuple
+from typing import Any, Callable, Dict, Hashable, List, Tuple
 
 import numpy as np
 
@@ -70,6 +70,13 @@ class DeviceSlabCache:
             out = dict(self.stats)
             out["entries"] = len(self._entries)
             return out
+
+    def items(self) -> List[Tuple[Hashable, str, Any]]:
+        """``(key, field, value)`` for every cached field — a copy taken
+        under the lock, for inspecting what is resident and where."""
+        with self._lock:
+            return [(k, f, v) for k, entry in self._entries.items()
+                    for f, v in entry.items()]
 
     def get_or_build(self, key: Hashable, field: str,
                      build: Callable[[], Any]) -> Any:

@@ -196,6 +196,15 @@ def make_sharded_multi_search(mesh: Mesh, x0: int, y0: int, l: int, k: int,
     return jax.jit(shmap), (db_spec, q_spec) + extra_spec, out_spec
 
 
+def put_sharded(mesh: Mesh, tree, specs):
+    """Upload a host pytree straight to its shards: each device receives
+    only its own block of every leaf (``specs`` mirrors ``tree`` with a
+    PartitionSpec per leaf), so nothing lands whole on one device first."""
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.device_put(tree, shardings)
+
+
 def assign_lb_specs(batch_axes: Sequence[str]) -> Tuple[Tuple, Tuple]:
     """PartitionSpecs for the stage-1.5 assignment-LB operands
     (DESIGN.md §16): the replicated stacked query branch block

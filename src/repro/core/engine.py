@@ -418,12 +418,21 @@ class BatchedFilterEval:
         if self.backend == "distributed":
             np_ = _pad_to(np_, self.n_shards)
         key, sub = self._gather_cached(uidx, np_)
-        dev = self.device_cache.get_or_build(
-            key, "lb_db",
-            lambda: tuple(jnp.asarray(x) for x in
-                          (sub.bvlab, sub.bdeg, sub.behist, sub.nv)))
         qvp, qdp, qehp, qnp = aops.pad_query_block(qv, qd, qeh, qn)
-        qargs = tuple(jnp.asarray(x) for x in (qvp, qdp, qehp, qnp))
+        host_db = (sub.bvlab, sub.bdeg, sub.behist, sub.nv)
+        host_q = (qvp, qdp, qehp, qnp)
+        if self.backend == "distributed":
+            from repro.core import distributed as dist
+            q_specs, db_specs = dist.assign_lb_specs(self._batch_axes)
+            dev = self.device_cache.get_or_build(
+                key, "lb_db",
+                lambda: dist.put_sharded(self.mesh, host_db, db_specs))
+            qargs = dist.put_sharded(self.mesh, host_q, q_specs)
+        else:
+            dev = self.device_cache.get_or_build(
+                key, "lb_db",
+                lambda: tuple(jnp.asarray(x) for x in host_db))
+            qargs = tuple(jnp.asarray(x) for x in host_q)
         if self.backend == "pallas":
             qb_t, bb_t = self.lb_tile_table.lookup(
                 qvp.shape[0], np_, qvp.shape[1], sub.bvlab.shape[1])
@@ -459,7 +468,7 @@ class BatchedFilterEval:
         self.n_shards = int(np.prod([mesh.shape[a] for a in batch_axes]))
         self._model_size = (1 if model_axis is None
                             else int(mesh.shape[model_axis]))
-        self._dist_fn, _, _ = dist.make_sharded_multi_search(
+        self._dist_fn, self._dist_specs, _ = dist.make_sharded_multi_search(
             mesh, self.partition.x0, self.partition.y0, self.partition.l,
             self.k, batch_axes=batch_axes, model_axis=model_axis,
             slab=self.slab_layout, n_entries=self.slab.U)
@@ -735,9 +744,7 @@ class BatchedFilterEval:
         block) triggers an exact host-side re-evaluation of that shard's
         slab rows for that query — candidates are never silently dropped.
         """
-        import jax
-        import jax.numpy as jnp
-
+        from repro.core import distributed as dist
         from repro.core import jax_compat as jc
 
         S = self.n_shards
@@ -746,19 +753,21 @@ class BatchedFilterEval:
         key, sub = self._gather_cached(idx, n_pad)
         qp = _pad_to(Q, _Q_PAD)
         qb = self.stack_queries(list(qs) + [qs[-1]] * (qp - Q))
+        # every upload goes straight to its shards (DESIGN.md §10)
+        db_spec, q_spec, *extra_spec = self._dist_specs
         extra: Tuple = ()
         if self.slab_layout == "hot":
             # batched CSR tail correction, sharded with the slab rows —
             # per (query, graph), so rebuilt per batch (never cached)
             cdt = sub.tail_minsum_batch(qb.fd).astype(np.int32)
             qb = qb._replace(fd=qb.fd[:, :sub.hot_d])
-            extra = (jnp.asarray(cdt),)
+            extra = dist.put_sharded(self.mesh, (cdt,), tuple(extra_spec))
         elif self.slab_layout == "packed":
             extra = self.device_cache.get_or_build(
                 key, "dist_packed",
-                lambda: tuple(jnp.asarray(x) for x in
-                              (sub.packed.words, sub.packed.sb,
-                               sub.packed.widths)))
+                lambda: dist.put_sharded(
+                    self.mesh, (sub.packed.words, sub.packed.sb,
+                                sub.packed.widths), tuple(extra_spec)))
         # vocab dim must divide 'model' on the vocab-sharded layout
         upad = (0 if self._model_axis is None
                 else (-sub.fd.shape[1]) % self._model_size)
@@ -767,14 +776,14 @@ class BatchedFilterEval:
             db = sub.base_arrays()
             if upad:
                 db = db._replace(fd=np.pad(db.fd, [(0, 0), (0, upad)]))
-            return DBArrays(*[jnp.asarray(x) for x in db])
+            return dist.put_sharded(self.mesh, DBArrays(*db), db_spec)
         db_dev = self.device_cache.get_or_build(key, "dist_db", _upload_db)
         if upad:
             qb = qb._replace(fd=np.pad(qb.fd, [(0, 0), (0, upad)]))
         with jc.set_mesh(self.mesh):
             sids, bnds, n_pass = self._dist_fn(
-                db_dev, QueryArrays(*[jnp.asarray(x) for x in qb]),
-                *extra)
+                db_dev, dist.put_sharded(self.mesh, QueryArrays(*qb),
+                                         q_spec), *extra)
         sids = np.asarray(sids)
         bnds = np.asarray(bnds)
         n_pass = np.asarray(n_pass)

@@ -1,10 +1,7 @@
-"""Version-compat shims over the moving jax sharding API surface.
+"""One-line wrappers over jax's sharding API, as the repo uses it.
 
-The repo targets the newest jax (``jax.shard_map`` with ``check_vma``,
-``jax.sharding.AxisType`` / ``set_mesh``) but must also run on the older
-release baked into the CI container (0.4.x: ``jax.experimental.shard_map``
-with ``check_rep``, no AxisType, no mesh context manager).  Everything that
-touches those APIs goes through here so the difference lives in one place.
+Every mesh is built with Auto axis types, and every ``shard_map`` turns
+replication checking off (the repo's collectives are explicit).
 
 Usage:
     from repro.core import jax_compat as jc
@@ -15,68 +12,26 @@ Usage:
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``{"axis_types": (AxisType.Auto,) * n}`` on new jax, ``{}`` on old.
-
-    Old jax has neither ``jax.sharding.AxisType`` nor the ``axis_types``
-    parameter on ``jax.make_mesh``; every mesh there behaves like Auto.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+set_mesh = jax.sharding.set_mesh
+named_axis_size = jax.lax.axis_size
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
+    """``jax.make_mesh`` with Auto axis types."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **axis_types_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map(..., check_vma=False)`` or the experimental fallback.
-
-    Replication checking is disabled on both paths (the repo's collectives
-    are explicit; the check only costs tracing time and has been renamed
-    between releases).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
-def set_mesh(mesh):
-    """Context manager form of ``jax.sharding.set_mesh`` (no-op on old jax).
-
-    On old jax all our distributed entry points pass explicit shardings to
-    ``jax.jit``, so there is nothing the ambient-mesh context needs to do.
-    """
-    ctx = (getattr(jax.sharding, "set_mesh", None)
-           or getattr(jax.sharding, "use_mesh", None))
-    if ctx is None:
-        return contextlib.nullcontext(mesh)
-    return ctx(mesh)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_size(mesh, axis_name: str) -> int:
-    """Static mesh-axis size (``jax.lax.axis_size`` is newer than 0.4.x)."""
+    """Static size of one mesh axis."""
     return int(mesh.shape[axis_name])
-
-
-def named_axis_size(axis_name: str):
-    """``jax.lax.axis_size`` inside a shard_map/pmap body, with the classic
-    ``psum(1, axis)`` fallback (constant-folds to a static int)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)

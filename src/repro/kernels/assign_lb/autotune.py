@@ -19,13 +19,15 @@ from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.kernels.qgram_filter.autotune import legal_tile
+
 DEFAULT_TILES: Tuple[int, int] = (8, 128)
 DEFAULT_PATH = os.path.normpath(os.path.join(
     os.path.dirname(__file__), "..", "..", "..", "..",
     "artifacts", "tune", "assign_lb.json"))
 
-QB_CANDIDATES = (4, 8, 16)
-BB_CANDIDATES = (64, 128, 256)
+QB_CANDIDATES = (8, 16)
+BB_CANDIDATES = (128, 256)
 
 
 def canonical_shape(Q: int, N: int, VMq: int, VM: int
@@ -56,8 +58,9 @@ class TileTable:
     def lookup(self, Q: int, N: int, VMq: int, VM: int) -> Tuple[int, int]:
         qb, bb = self.entries.get(_key(canonical_shape(Q, N, VMq, VM)),
                                   self.default)
-        # the padded launch shapes always divide by a clamped tile
-        return (min(qb, Q), min(bb, N))
+        # the padded launch shapes always divide by a clamped tile, and
+        # the TPU lowering wants (8, 128)-aligned (or whole-axis) blocks
+        return legal_tile(qb, Q, 8), legal_tile(bb, N, 128)
 
     def __len__(self) -> int:
         return len(self.entries)
@@ -125,7 +128,7 @@ def sweep(shapes: Iterable[Tuple[int, int, int, int]], *, ne: int = 3,
         best, best_t = DEFAULT_TILES, np.inf
         seen = set()
         for qb, bb in candidates:
-            eff = (min(qb, Q), min(bb, N_t))
+            eff = (legal_tile(qb, Q, 8), legal_tile(bb, N_t, 128))
             if eff in seen:
                 continue
             seen.add(eff)

@@ -3,20 +3,21 @@
 
 One (qb, bb) tile prices every query-vertex / db-vertex branch pair of
 its block and reduces straight to the per-pair LB — a pure min-reduce,
-no cross-tile accumulation, so the grid is just (Q/QB, N/BB) and the
-kernel needs no scratch.
+no cross-tile accumulation, so the grid is just (Q/QB, N/BB); the row
+sums and column minima build up in VMEM scratch across the query-vertex
+loop.
 
 The db-side branch operands (labels, degrees, incident edge-label
-histograms) are the device-resident slab arrays; the query block rides a
-leading Q axis like the fused filter kernel (§13).  True query vertex
-counts arrive as an SMEM (QB, 1) scalar block; db vertex counts as a
+histograms) are the device-resident slab arrays, the histograms as NE
+leading (BB, VM) planes.  The query block is small, so all of it sits in
+SMEM — vertex counts, labels, degrees and label-major histograms — and
+the kernel reads it one scalar at a time: the TPU lowering has no
+strided gather along the minor dims.  Db vertex counts arrive as a
 (BB, 1) VMEM column.  Pad vertices price exactly as the ε column (the
 ``branch_features`` padding contract), so only the two sums mask.
 
-The static Python loop over the query-vertex axis keeps every
-intermediate at rank 3 — (QB, BB, VM) — which the TPU vector unit
-handles natively; VMq is shape-bucketed (ops.VM_BASE ladder) so the
-unroll count stays bounded per compiled program.
+The loop over the query-vertex axis keeps every intermediate at rank 3 —
+(QB, BB, VM) — which the TPU vector unit handles natively.
 """
 from __future__ import annotations
 
@@ -30,47 +31,55 @@ from jax.experimental.pallas import tpu as pltpu
 N_SCALARS = 1                 # per-query scalar block: [true vertex count]
 
 
-def _lb_kernel(scalars_ref,   # SMEM (QB, 1) int32: query vertex counts
-               qv_ref,        # (QB, VMq) int32 query vertex labels (pad -1)
-               qd_ref,        # (QB, VMq) int32 query degrees (pad 0)
-               qeh_ref,       # (QB, VMq, NE) int32 incident-label hists
+def _lb_kernel(qn_ref,        # SMEM (QB, 1) int32: query vertex counts
+               qv_ref,        # SMEM (QB, VMq) int32 query vertex labels
+               qd_ref,        # SMEM (QB, VMq) int32 query degrees
+               qeh_ref,       # SMEM (QB, NE*VMq) int32 hists, label-major
                dv_ref,        # (BB, VM) int32 db vertex labels (pad -1)
                dd_ref,        # (BB, VM) int32 db degrees (pad 0)
-               deh_ref,       # (BB, VM, NE) int32 db incident-label hists
+               deh_ref,       # (NE, BB, VM) int32 db incident-label hists
                dn_ref,        # (BB, 1) int32 db vertex counts
-               lb_ref):       # (QB, BB) int32 out
+               lb_ref,        # (QB, BB) int32 out
+               colmin_ref,    # VMEM (QB, BB, VM) scratch: column minima
+               rowsum_ref):   # VMEM (QB, BB) scratch: row-min sums
     QB, VMq = qv_ref.shape
+    NE, BB, VM = deh_ref.shape
     dv = dv_ref[...]
     dd = dd_ref[...]
-    deh = deh_ref[...]
-    BB, VM = dv.shape
-    NE = deh.shape[2]
 
-    # per-query scalar column as a (QB, 1) vector; SMEM reads stay
-    # scalar (TPU-safe), QB is static so the stack unrolls
-    qn = jnp.stack([scalars_ref[r, 0] for r in range(QB)])[:, None]
+    def qcol(ref, col):
+        # one query-side value per query of the block, splat across that
+        # query's (BB, VM) plane: scalar SMEM reads (the query block is
+        # tiny), QB static; a (QB, 1, 1) vector cannot broadcast over
+        # sublanes and lanes at once
+        return jnp.concatenate([jnp.full((1, BB, VM), ref[r, col], jnp.int32)
+                                for r in range(QB)], axis=0)
 
-    rowsum = jnp.zeros((QB, BB), jnp.int32)
-    colmin = jnp.broadcast_to((2 + dd)[None, :, :], (QB, BB, VM))
-    for u in range(VMq):
-        lbl = 2 * (qv_ref[:, u][:, None, None] != dv[None, :, :]
-                   ).astype(jnp.int32)
-        dmax = jnp.maximum(qd_ref[:, u][:, None, None], dd[None, :, :])
+    qn = qcol(qn_ref, 0)
+    colmin_ref[...] = jnp.broadcast_to((2 + dd)[None, :, :], (QB, BB, VM))
+    rowsum_ref[...] = jnp.zeros((QB, BB), jnp.int32)
+
+    @pl.loop(0, VMq)
+    def _(u):
+        qd_u = qcol(qd_ref, u)
+        lbl = 2 * (qcol(qv_ref, u) != dv[None, :, :]).astype(jnp.int32)
+        dmax = jnp.maximum(qd_u, dd[None, :, :])
         inter = jnp.zeros((QB, BB, VM), jnp.int32)
         for e in range(NE):
-            inter += jnp.minimum(qeh_ref[:, u, e][:, None, None],
-                                 deh[None, :, :, e])
+            inter += jnp.minimum(qcol(qeh_ref, e * VMq + u),
+                                 deh_ref[e][None, :, :])
         c2 = lbl + dmax - inter                           # (QB, BB, VM)
-        rmin = jnp.minimum(c2.min(axis=2),
-                           (2 + qd_ref[:, u])[:, None])   # (QB, BB)
-        rowsum += jnp.where(u < qn, rmin, 0)
-        colmin = jnp.minimum(colmin, c2)
+        # row minimum over the db vertices and ε, summed over the real
+        # query vertices only (c2 >= 0, so a masked row contributes 0)
+        rowsum_ref[...] += jnp.where(u < qn, jnp.minimum(c2, 2 + qd_u),
+                                     0).min(axis=2)
+        colmin_ref[...] = jnp.minimum(colmin_ref[...], c2)
 
     dn = dn_ref[...][:, 0]                                # (BB,)
     vvalid = (jax.lax.broadcasted_iota(jnp.int32, (BB, VM), 1)
               < dn[:, None])
-    colsum = jnp.where(vvalid[None, :, :], colmin, 0).sum(axis=2)
-    lb2 = jnp.maximum(rowsum, colsum)
+    colsum = jnp.where(vvalid[None, :, :], colmin_ref[...], 0).sum(axis=2)
+    lb2 = jnp.maximum(rowsum_ref[...], colsum)
     lb_ref[...] = ((lb2 + 1) // 2).astype(jnp.int32)
 
 
@@ -80,13 +89,18 @@ def assign_lb_call(qv, qd, qeh, qn, dv, dd, deh, dn, *, qb: int = 8,
     """Raw pallas_call; shapes must already be tile-aligned.
 
     qv/qd (Q, VMq); qeh (Q, VMq, NE); qn (Q,); dv/dd (N, VM);
-    deh (N, VM, NE); dn (N,).  Returns (Q, N) int32 LBs.
+    deh (N, VM, NE); dn (N,).  Returns (Q, N) int32 LBs.  The edge-label
+    axis moves in front here (``qeh`` label-major per query row, ``deh``
+    as NE planes), so the kernel reads whole (BB, VM) planes and scalar
+    query entries — no strided minor-dim gather.
     """
     Q, VMq = qv.shape
     N, VM = dv.shape
     NE = deh.shape[2]
     assert Q % qb == 0 and N % bb == 0, (Q, N, qb, bb)
     scalars = jnp.asarray(qn, jnp.int32).reshape(Q, N_SCALARS)
+    qeh_lm = jnp.transpose(qeh, (0, 2, 1)).reshape(Q, NE * VMq)
+    deh_planes = jnp.transpose(deh, (2, 0, 1))
     dn2 = jnp.asarray(dn, jnp.int32).reshape(N, 1)
     grid = (Q // qb, N // bb)
     return pl.pallas_call(
@@ -94,16 +108,21 @@ def assign_lb_call(qv, qd, qeh, qn, dv, dd, deh, dn, *, qb: int = 8,
         grid=grid,
         in_specs=[
             pl.BlockSpec((qb, N_SCALARS), lambda q, i: (q, 0),
-                         memory_space=pltpu.SMEM),               # scalars
-            pl.BlockSpec((qb, VMq), lambda q, i: (q, 0)),        # qv
-            pl.BlockSpec((qb, VMq), lambda q, i: (q, 0)),        # qd
-            pl.BlockSpec((qb, VMq, NE), lambda q, i: (q, 0, 0)),  # qeh
-            pl.BlockSpec((bb, VM), lambda q, i: (i, 0)),         # dv
-            pl.BlockSpec((bb, VM), lambda q, i: (i, 0)),         # dd
-            pl.BlockSpec((bb, VM, NE), lambda q, i: (i, 0, 0)),  # deh
-            pl.BlockSpec((bb, 1), lambda q, i: (i, 0)),          # dn
+                         memory_space=pltpu.SMEM),                # qn
+            pl.BlockSpec((qb, VMq), lambda q, i: (q, 0),
+                         memory_space=pltpu.SMEM),                # qv
+            pl.BlockSpec((qb, VMq), lambda q, i: (q, 0),
+                         memory_space=pltpu.SMEM),                # qd
+            pl.BlockSpec((qb, NE * VMq), lambda q, i: (q, 0),
+                         memory_space=pltpu.SMEM),                # qeh
+            pl.BlockSpec((bb, VM), lambda q, i: (i, 0)),          # dv
+            pl.BlockSpec((bb, VM), lambda q, i: (i, 0)),          # dd
+            pl.BlockSpec((NE, bb, VM), lambda q, i: (0, i, 0)),   # deh
+            pl.BlockSpec((bb, 1), lambda q, i: (i, 0)),           # dn
         ],
         out_specs=pl.BlockSpec((qb, bb), lambda q, i: (q, i)),
         out_shape=jax.ShapeDtypeStruct((Q, N), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((qb, bb, VM), jnp.int32),
+                        pltpu.VMEM((qb, bb), jnp.int32)],
         interpret=interpret,
-    )(scalars, qv, qd, qeh, dv, dd, deh, dn2)
+    )(scalars, qv, qd, qeh_lm, dv, dd, deh_planes, dn2)

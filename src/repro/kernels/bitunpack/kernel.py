@@ -9,13 +9,17 @@ payloads are word-aligned: SB[k] is a word offset and no entry straddles a
 word.
 
 Kernel layout:
-  * the packed word stream lives as a full-array VMEM ref — per-device
-    Psi shards are ~1-2 MB for PubChem-scale DBs (25M graphs / 256 chips),
-    comfortably inside the 16 MB VMEM budget (DESIGN.md §3);
-  * SB (word offsets) and widths live in SMEM (scalar memory);
-  * grid = one step per block; each step dynamic-slices its <=128-word
-    window, unpacks all five width hypotheses with static shift/mask
-    vector code, and selects by the block's width — pure VPU work.
+  * the packed word stream stays in HBM as ``(rows, 128)`` words; a block's
+    <=128-word window starting at SB[k] always lies within two consecutive
+    rows, which the kernel DMAs into VMEM (row-granular copies at a dynamic
+    row index — no unaligned slice of a 1-D ref);
+  * each grid step decodes ``GROUP`` = 8 blocks, so the output block is one
+    (8, 128) int32 tile; the per-block (word offset, width) pairs arrive as
+    an SMEM (8, 2) block;
+  * decoding is a per-lane gather within those two rows: entry e of a
+    width-w block reads word ``SB % 128 + e // (32 / w)`` at bit
+    ``32 - w - (e % (32 / w)) * w`` — shifts and masks on the VPU, with
+    the width-dependent divisions written as shifts.
 """
 from __future__ import annotations
 
@@ -29,36 +33,42 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK_ENTRIES = 128
 WIDTHS = (2, 4, 8, 16, 32)
 MAX_WORDS = BLOCK_ENTRIES * 32 // 32  # width=32 worst case: 128 words
+GROUP = 8                             # blocks decoded per grid step
+LANES = 128
 
 
-def _unpack_width(win_u32: jax.Array, width: int) -> jax.Array:
-    """Static-width unpack of the first 128*width/32 words -> (128,) int32.
+def _kernel(meta_ref,      # SMEM (GROUP, 2) int32: word offset, bit width
+            words_hbm,     # ANY (rows, 128) int32 — packed stream
+            out_ref,       # VMEM (GROUP, 128) int32 — decoded blocks
+            lo_buf,        # VMEM (GROUP, 128) int32: row holding SB[k]
+            hi_buf,        # VMEM (GROUP, 128) int32: the row after it
+            sem):          # DMA semaphores (2, GROUP)
+    copies = []
+    for g in range(GROUP):
+        row = meta_ref[g, 0] // LANES
+        for half, buf in enumerate((lo_buf, hi_buf)):
+            cp = pltpu.make_async_copy(words_hbm.at[pl.ds(row + half, 1)],
+                                       buf.at[pl.ds(g, 1)], sem.at[half, g])
+            cp.start()
+            copies.append(cp)
+    for cp in copies:
+        cp.wait()
 
-    MSB-first within each word: entry e of word w sits at bit
-    32 - width - e*width.
-    """
-    per = 32 // width
-    n_words = BLOCK_ENTRIES // per
-    words = win_u32[:n_words]
-    shifts = (32 - width - jnp.arange(per, dtype=jnp.uint32) * width)
-    vals = jax.lax.shift_right_logical(
-        words[:, None], jnp.broadcast_to(shifts[None, :], (n_words, per)))
-    vals = vals & jnp.uint32((1 << width) - 1)
-    return vals.reshape(BLOCK_ENTRIES).astype(jnp.int32)
-
-
-def _kernel(sb_ref,        # SMEM (n_blocks,) int32 — word offset per block
-            w_ref,         # SMEM (n_blocks,) int32 — bit width per block
-            words_ref,     # VMEM (n_words_padded,) int32 — packed stream
-            out_ref):      # (1, 128) int32 — decoded block
-    k = pl.program_id(0)
-    start = sb_ref[k]
-    width = w_ref[k]
-    win = pl.load(words_ref, (pl.ds(start, MAX_WORDS),)).astype(jnp.uint32)
-    out = _unpack_width(win, WIDTHS[0])
-    for wbits in WIDTHS[1:]:
-        out = jnp.where(width == wbits, _unpack_width(win, wbits), out)
-    out_ref[0, :] = out
+    off = jnp.stack([meta_ref[g, 0] % LANES for g in range(GROUP)])[:, None]
+    width = jnp.stack([meta_ref[g, 1] for g in range(GROUP)])[:, None]
+    # log2(width) for width in WIDTHS; entries per word = 32 >> lw
+    lw = sum((width >= w).astype(jnp.int32) for w in WIDTHS)
+    e = jax.lax.broadcasted_iota(jnp.int32, (GROUP, LANES), 1)
+    word_idx = off + jax.lax.shift_right_logical(e, 5 - lw)
+    slot = e & (jax.lax.shift_right_logical(jnp.int32(32), lw) - 1)
+    lane = word_idx & (LANES - 1)
+    word = jnp.where(word_idx < LANES,
+                     jnp.take_along_axis(lo_buf[...], lane, axis=1),
+                     jnp.take_along_axis(hi_buf[...], lane, axis=1))
+    shift = 32 - width - jax.lax.shift_left(slot, lw)
+    mask = jnp.where(width == 32, jnp.int32(-1),
+                     jax.lax.shift_left(jnp.int32(1), width) - 1)
+    out_ref[...] = jax.lax.shift_right_logical(word, shift) & mask
 
 
 @functools.partial(jax.jit, static_argnames=("n_blocks", "interpret"))
@@ -67,17 +77,31 @@ def bitunpack_call(sb, widths, words, *, n_blocks: int,
     """Decode all blocks: returns (n_blocks, 128) int32.
 
     ``words`` must be padded with >= MAX_WORDS trailing words so the last
-    window never reads out of bounds.
+    window never reads out of bounds; here it is further padded to whole
+    128-word rows plus one spare row for the two-row window.
     """
-    return pl.pallas_call(
+    g_pad = -(-n_blocks // GROUP) * GROUP
+    meta = jnp.stack([sb, widths], axis=1).astype(jnp.int32)
+    # pad blocks decode word 0 at width 2 and are sliced off below
+    meta = jnp.pad(meta, ((0, g_pad - n_blocks), (0, 0)),
+                   constant_values=WIDTHS[0])
+    meta = meta.at[n_blocks:, 0].set(0)
+    n_rows = -(-words.shape[0] // LANES) + 1
+    rows = jnp.pad(words.astype(jnp.int32),
+                   (0, n_rows * LANES - words.shape[0])).reshape(n_rows, LANES)
+    out = pl.pallas_call(
         _kernel,
-        grid=(n_blocks,),
+        grid=(g_pad // GROUP,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((GROUP, 2), lambda k: (k, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, BLOCK_ENTRIES), lambda k: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, BLOCK_ENTRIES), jnp.int32),
+        out_specs=pl.BlockSpec((GROUP, BLOCK_ENTRIES), lambda k: (k, 0)),
+        out_shape=jax.ShapeDtypeStruct((g_pad, BLOCK_ENTRIES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((GROUP, LANES), jnp.int32),
+                        pltpu.VMEM((GROUP, LANES), jnp.int32),
+                        pltpu.SemaphoreType.DMA((2, GROUP))],
         interpret=interpret,
-    )(sb, widths, words)
+    )(meta, rows)
+    return out[:n_blocks]

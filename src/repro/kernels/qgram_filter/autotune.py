@@ -36,9 +36,12 @@ DEFAULT_PATH = os.path.normpath(os.path.join(
 
 # powers of two only: shape_bucket guarantees any of these tiles an even
 # grid after min(tile, bucket)
-QB_CANDIDATES = (4, 8, 16)
-BB_CANDIDATES = (64, 128, 256)
+QB_CANDIDATES = (8, 16)
+BB_CANDIDATES = (128, 256)
 BU_CANDIDATES = (128, 256, 512)
+# the finalize step keeps (qb, bb, VM)-sized intermediates in VMEM; on a
+# v5e, qb * bb above this overflows it (16 x 256 is refused)
+MAX_QB_BB = 2048
 
 
 def canonical_shape(Q: int, B: int, U: int) -> Tuple[int, int, int]:
@@ -54,6 +57,30 @@ def _key(shape: Tuple[int, int, int]) -> str:
     return "x".join(str(int(s)) for s in shape)
 
 
+def legal_tile(tile: int, n: int, align: int) -> int:
+    """A block size the TPU lowering accepts on an axis padded to ``n``:
+    at least ``align`` (8 on the sublane axis, 128 on the lane axis) or
+    else the whole axis.  Bucket sizes are powers of two or multiples of
+    the cap, so the result still tiles ``n`` evenly."""
+    return min(max(int(tile), align), int(n))
+
+
+def legal_tiles(tiles: Sequence[int], shape: Sequence[int]
+                ) -> Tuple[int, int, int]:
+    """Clamp (qb, bb, bu) to what the TPU compiler accepts on a canonical
+    (Q, B, U) shape: (8, 128)-aligned blocks (or whole axes), and
+    qb * bb within ``MAX_QB_BB`` (bb shrinks first).  Idempotent, so a
+    table of legal tiles reads back unchanged."""
+    Q, B, U = shape
+    qb = legal_tile(tiles[0], Q, 8)
+    bb = legal_tile(tiles[1], B, 128)
+    while qb * bb > MAX_QB_BB and bb > 128:
+        bb //= 2
+    while qb * bb > MAX_QB_BB and qb > 8:
+        qb //= 2
+    return qb, bb, legal_tile(tiles[2], U, 128)
+
+
 class TileTable:
     """Shape-bucket -> (qb, bb, bu) lookup with a default fallback."""
 
@@ -66,7 +93,9 @@ class TileTable:
         self.timed_on = timed_on
 
     def lookup(self, Q: int, B: int, U: int) -> Tuple[int, int, int]:
-        return self.entries.get(_key(canonical_shape(Q, B, U)), self.default)
+        shape = canonical_shape(Q, B, U)
+        return legal_tiles(self.entries.get(_key(shape), self.default),
+                           shape)
 
     def __len__(self) -> int:
         return len(self.entries)
@@ -152,7 +181,7 @@ def sweep(shapes: Iterable[Tuple[int, int, int]], *,
         best, best_t = DEFAULT_TILES, np.inf
         seen = set()
         for qb, bb, bu in candidates:
-            eff = (min(qb, Q), min(bb, B_t), min(bu, U))
+            eff = legal_tiles((qb, bb, bu), (Q, B_t, U))
             if eff in seen:
                 continue
             seen.add(eff)

@@ -9,7 +9,6 @@ import sys
 import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-sys.path.insert(0, os.path.dirname(__file__))  # tests/_propshim.py fallback
 
 
 def pytest_configure(config):

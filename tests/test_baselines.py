@@ -2,10 +2,7 @@
 admissible, and the paper's comparative claims should hold in trend."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline CI: deterministic fallback (tests/_propshim.py)
-    from _propshim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import baselines
 from repro.core.verify import ged_bruteforce

@@ -7,10 +7,7 @@ paths agree exactly.
 """
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline CI: deterministic fallback (tests/_propshim.py)
-    from _propshim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import filters
 from repro.core.verify import ged_bruteforce

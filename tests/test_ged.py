@@ -1,10 +1,7 @@
 """Exact-GED verification tests: A* vs brute force + metric properties."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline CI: deterministic fallback (tests/_propshim.py)
-    from _propshim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.verify import ged_bruteforce, ged_exact, ged_upto
 from repro.graphs.generators import perturb_graph, random_graph

@@ -173,10 +173,7 @@ def test_assign_lb_match_parity(lb_db, backend, slab):
 
 # ---- top-k modality (adaptive-τ escalation, DESIGN.md §15) -----------------
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline CI: deterministic fallback (tests/_propshim.py)
-    from _propshim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 @pytest.fixture(scope="module")

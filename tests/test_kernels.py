@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline CI: deterministic fallback (tests/_propshim.py)
-    from _propshim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.bitunpack.ops import pack_hybrid, unpack_hybrid
 from repro.kernels.bitunpack.ref import unpack_hybrid_ref

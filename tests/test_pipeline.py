@@ -142,6 +142,30 @@ def test_process_pool_scheduler_direct(small_db, flat):
         assert sorted(job.matches) == res.matches
 
 
+def test_verify_pool_child_never_imports_jax(small_db):
+    """A verify-pool worker only unpickles a ``GEDSearch`` and runs
+    ``run_search_slice``; that import chain must stay free of jax, so a
+    worker can never initialize a backend and take the chip from the
+    serving process.  Replays the worker's job in a fresh interpreter."""
+    import pickle
+    search = GEDSearch(small_db[0], small_db[1], 3)
+    child = textwrap.dedent("""
+        import pickle, sys
+        search = pickle.loads(sys.stdin.buffer.read())
+        from repro.core.verify import run_search_slice
+        d, search = run_search_slice(search, None, None)
+        print(d, "jax" in sys.modules)
+    """)
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, "-c", child],
+                         input=pickle.dumps(search), capture_output=True,
+                         env=env, timeout=120, check=True)
+    decision, jax_loaded = out.stdout.decode().split()
+    assert int(decision) == ged_upto(small_db[0], small_db[1], 3)
+    assert jax_loaded == "False"
+
+
 def test_pool_worker_kill_resumes_at_frontier(small_db, flat, monkeypatch):
     """A worker killed mid-slice re-enqueues the resumable GEDSearch at
     its last frontier — one construction per pair, never a restart —

@@ -1,10 +1,7 @@
 """Succinct structures (Section 5.2): rank, coders, hybrid blocks."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline CI: deterministic fallback (tests/_propshim.py)
-    from _propshim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.succinct import (BitReader, BitVector, BitWriter,
                                  HybridEncodedArray, delta_length,

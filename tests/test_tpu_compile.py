@@ -1,0 +1,116 @@
+"""Ahead-of-time compiles of the served path's Pallas kernels for a TPU v5e.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a *described* ``v5e:2x2`` topology.  Each test compiles one kernel at
+the widths the AIDS deployment (configs/msq_aids.py: 62 vertex labels, 3
+edge labels, |V| <= 64, a degree q-gram vocabulary of ~1.2k ids) serves
+with, and asserts a Mosaic kernel (``tpu_custom_call``) is in the result —
+so a kernel the chip's compiler refuses fails here, at no chip time.
+Interpret mode cannot catch these: block shapes that break the (8, 128)
+tiling, strided minor-dim gathers, or tiles that overflow VMEM.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every test worker imports this file.
+"""
+import json
+import os
+
+import pytest
+
+# AIDS-sized serving shapes: padded query block, region-bucket rows, the
+# F_D width of each slab layout (dense vocabulary bucket, hot prefix,
+# packed decode width), db vertex width, labels
+Q, B, VM, NV, NE = 8, 4096, 64, 62, 3
+U_BY_LAYOUT = {"dense": 1536, "hot": 512, "packed": 1280}
+TUNE_DIR = os.path.join(os.path.dirname(__file__), "..", "artifacts", "tune")
+
+
+def _table_keys(name):
+    with open(os.path.join(TUNE_DIR, name), encoding="utf-8") as f:
+        return sorted(json.load(f)["entries"])
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def compile_tpu(topo):
+    """``compile_tpu(fn, *shapes)`` -> the compiled text, for one v5e chip.
+    The persistent compile cache is off meanwhile: an entry written for a
+    described chip cannot be read back without one."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+                for s in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+        return text
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _filter_shapes(q, b, u):
+    return [(q, 6), (b, u), (q, u), (b, NV), (q, NV), (b, NE), (q, NE),
+            (b, VM), (q, VM), (b, 5), (q, b)]
+
+
+@pytest.mark.parametrize("layout", sorted(U_BY_LAYOUT))
+def test_qgram_filter_compiles_at_aids_widths(compile_tpu, layout):
+    from repro.kernels.qgram_filter.autotune import TileTable
+    from repro.kernels.qgram_filter.ops import fused_filter_bounds_batched
+    U = U_BY_LAYOUT[layout]
+    qb, bb, bu = TileTable().lookup(Q, B, U)
+    compile_tpu(lambda *a: fused_filter_bounds_batched(
+        *a, qb=qb, bb=bb, bu=bu, interpret=False), *_filter_shapes(Q, B, U))
+
+
+@pytest.mark.parametrize("key", _table_keys("qgram_filter.json"))
+def test_qgram_filter_tuned_tiles_compile(compile_tpu, key):
+    """Every tile the persisted table hands out is one the compiler
+    accepts (``TileTable.lookup`` clamps to legal tiles)."""
+    from repro.kernels.qgram_filter.autotune import load_tile_table
+    from repro.kernels.qgram_filter.ops import fused_filter_bounds_batched
+    q, b, u = (int(x) for x in key.split("x"))
+    qb, bb, bu = load_tile_table(None).lookup(q, b, u)
+    compile_tpu(lambda *a: fused_filter_bounds_batched(
+        *a, qb=qb, bb=bb, bu=bu, interpret=False), *_filter_shapes(q, b, u))
+
+
+@pytest.mark.parametrize("key", _table_keys("assign_lb.json") + ["8x512x64x64"])
+def test_assign_lb_compiles(compile_tpu, key):
+    """The last case is the AIDS serving shape under the default tiles."""
+    from repro.kernels.assign_lb.autotune import load_tile_table
+    from repro.kernels.assign_lb.ops import assign_lb_bounds_batched
+    q, n, vmq, vm = (int(x) for x in key.split("x"))
+    qb, bb = load_tile_table(None).lookup(q, n, vmq, vm)
+    compile_tpu(lambda *a: assign_lb_bounds_batched(
+        *a, qb=qb, bb=bb, interpret=False),
+        (q, vmq), (q, vmq), (q, vmq, NE), (q,), (n, vm), (n, vm),
+        (n, vm, NE), (n,))
+
+
+def test_bitunpack_compiles_at_aids_widths(compile_tpu):
+    """The packed slab's per-launch decode: B rows of ceil(U / 128)
+    blocks, words at the widest (32-bit) payload plus the guard."""
+    from repro.kernels.bitunpack.kernel import bitunpack_call
+    n_blocks = B * -(-U_BY_LAYOUT["packed"] // 128)
+    compile_tpu(lambda sb, w, words: bitunpack_call(
+        sb, w, words, n_blocks=n_blocks), (n_blocks,), (n_blocks,),
+        (n_blocks * 128 + 128,))

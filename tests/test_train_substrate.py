@@ -7,10 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline CI: deterministic fallback (tests/_propshim.py)
-    from _propshim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.data import ShardedLoader, StragglerSimulator, SyntheticLMDataset
 from repro.optim import (adafactor, adamw, clip_by_global_norm,

@@ -2,10 +2,7 @@
 tree == flat equivalence, region reduction soundness, space accounting."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline CI: deterministic fallback (tests/_propshim.py)
-    from _propshim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.region import default_partition, group_by_region
 from repro.core.search import FlatMSQIndex, MSQIndex
