@@ -25,7 +25,8 @@ floor, not a speedup — the per-device win needs real accelerators
 verification ON: synchronous ``submit`` vs ``AsyncGraphQueryEngine``
 (``--pipeline-workers`` verifiers, batches of ``--pipeline-batch``),
 asserts bit-identical results, and records overlap-efficiency — how much
-of the device filter time ran *while* verification was in flight — to
+of the device filter time ran *while* verification was in flight, from
+the async engine's ``filter`` and ``verify`` spans — to
 ``artifacts/bench/query_throughput_pipeline.{csv,json}``.
 
 ``--obs-overhead`` measures span-recording overhead (DESIGN.md §17):
@@ -373,8 +374,10 @@ def run_pipeline(csv: Csv, n_db: int = 5000, n_queries: int = 64,
                  backend: str = "auto", workers: int = 2,
                  max_batch: int = 0, repeats: int = 1) -> Dict:
     """Sync submit vs the async pipelined engine, verification ON, with
-    filter/verify overlap accounting (device busy during verification)."""
+    filter/verify overlap accounting (device busy during verification)
+    from the async engine's ``filter`` and ``verify`` spans."""
     from repro.core.search import FlatMSQIndex
+    from repro.obs import Observability
     from repro.serve.graph_engine import GraphQuery, GraphQueryEngine
     from repro.serve.pipeline import AsyncGraphQueryEngine
 
@@ -393,26 +396,28 @@ def run_pipeline(csv: Csv, n_db: int = 5000, n_queries: int = 64,
 
     wall_async = np.inf
     for _ in range(repeats):
-        eng = GraphQueryEngine(flat, backend=backend, result_cache_size=0)
+        eng = GraphQueryEngine(flat, backend=backend, result_cache_size=0,
+                               obs=Observability(spans=True))
         run_pipe = AsyncGraphQueryEngine(eng, max_batch=max_batch,
                                          max_delay_s=0.002,
-                                         num_workers=workers,
-                                         record_intervals=True)
+                                         num_workers=workers)
         t0 = time.perf_counter()
         tickets = run_pipe.submit_many(reqs)
         run_out = [t.result(timeout=600) for t in tickets]
         wall = time.perf_counter() - t0
         run_pipe.close()
-        if wall < wall_async:   # keep wall + intervals from the same run
-            wall_async, apipe, out = wall, run_pipe, run_out
+        if wall < wall_async:   # keep wall + spans from the same run
+            wall_async, spans, out = wall, eng.obs.spans.spans(), run_out
 
     for got, want in zip(out, ref):
         assert got.candidates == want.candidates, "candidate sets diverged"
         assert got.matches == want.matches, "match sets diverged"
 
-    filter_busy = _union_length(apipe.filter_intervals)
-    verify_busy = _union_length(apipe.verify_intervals)
-    overlap = _overlap_length(apipe.filter_intervals, apipe.verify_intervals)
+    filter_iv = [(s.t0, s.t1) for s in spans if s.name == "filter"]
+    verify_iv = [(s.t0, s.t1) for s in spans if s.name == "verify"]
+    filter_busy = _union_length(filter_iv)
+    verify_busy = _union_length(verify_iv)
+    overlap = _overlap_length(filter_iv, verify_iv)
     qps_sync = n_queries / wall_sync
     qps_async = n_queries / wall_async
     rec = {"n_db": n_db, "n_queries": n_queries, "backend": eng.backend,

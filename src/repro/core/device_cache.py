@@ -18,14 +18,43 @@ dropped with it.  ``invalidate()`` empties the cache — called when the
 evaluator's slab is rebuilt (``BatchedFilterEval.rebuild_slab``) or when
 ``FlatMSQIndex.set_filter_eval`` replaces a registered evaluator, so a
 stale device copy can never outlive the slab it mirrors.
+
+Every lookup also counts in the ambient registry (``repro.obs``), under
+the ``engine.`` namespace the serving stats export:
+``slab_cache.<field>.hits`` / ``.misses``, ``slab_cache.evictions`` and
+``slab_cache.upload_bytes`` (every field but the host gather ``sub``).
+A miss's build is a ``slab_gather`` (``sub``) or ``slab_upload`` span
+with the field, its rows and bytes.
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Hashable, List, Tuple
 
 import numpy as np
+
+from repro.obs import ambient_count, ambient_span, current_obs
+
+
+def _payload(value) -> Tuple[int, int]:
+    """(leading rows, bytes) of the arrays a cached field holds: an array,
+    a tuple of them, or a gathered sub-slab dataclass."""
+    if hasattr(value, "nbytes") and hasattr(value, "shape"):
+        return (int(value.shape[0]) if value.ndim else 1), int(value.nbytes)
+    if dataclasses.is_dataclass(value):
+        parts = [getattr(value, f.name) for f in dataclasses.fields(value)]
+    elif isinstance(value, (tuple, list)):
+        parts = value
+    else:
+        return 0, 0
+    rows = nbytes = 0
+    for p in parts:
+        r, b = _payload(p)
+        rows = rows or r
+        nbytes += b
+    return rows, nbytes
 
 
 def bucket_key(idx: np.ndarray, n_pad: int) -> Tuple:
@@ -85,15 +114,31 @@ class DeviceSlabCache:
         arrays, pallas operands, ...) share the entry and its LRU slot."""
         with self._lock:
             entry = self._entries.get(key)
-            if entry is not None and field in entry:
+            hit = entry is not None and field in entry
+            if hit:
                 self._entries.move_to_end(key)
                 self.stats["hits"] += 1
-                return entry[field]
+                value = entry[field]
+        if hit:
+            ambient_count(f"engine.slab_cache.{field}.hits")
+            return value
         # build outside the lock: gathers/uploads are slow and re-entrant
         # callers (a field builder using another field) must not deadlock
         if self._faults is not None:
             self._faults.fire("device.cache", field=field)
-        value = build()
+        gather = field == "sub"
+        obs = current_obs()
+        with ambient_span("slab_gather" if gather else "slab_upload",
+                          field=field) as args:
+            value = build()
+            if not gather and obs is not None and obs.spans.enabled:
+                # a device copy is queued asynchronously: end the span
+                # when the bytes are on the device, not when it is queued
+                import jax
+                jax.block_until_ready(value)
+            rows, nbytes = _payload(value)
+            args.update(rows=rows, bytes=nbytes)
+        evicted = 0
         with self._lock:
             entry = self._entries.setdefault(key, {})
             self._entries.move_to_end(key)
@@ -103,6 +148,12 @@ class DeviceSlabCache:
             while len(self._entries) > self.max_entries:
                 self._entries.popitem(last=False)
                 self.stats["evictions"] += 1
+                evicted += 1
+        ambient_count(f"engine.slab_cache.{field}.misses")
+        if evicted:
+            ambient_count("engine.slab_cache.evictions", evicted)
+        if not gather:
+            ambient_count("engine.slab_cache.upload_bytes", nbytes)
         return value
 
     def invalidate(self) -> None:

@@ -47,7 +47,7 @@ from typing import Dict, List, Optional, Protocol, Sequence, Tuple, runtime_chec
 
 import numpy as np
 
-from repro.obs import current_obs, device_annotation
+from repro.obs import ambient_span, current_obs
 from repro.obs.health import FAILING, StageHealth
 
 from repro.core import arrays, filters
@@ -154,6 +154,9 @@ def _bounds_multi_jit(layout: str = "dense"):
     nonzero columns (``qids``/``qcnt``, zero-padded — pad slots contribute
     ``min(fd, 0) = 0``).  Bit-identical to the dense sweep, ~U/K times
     less work on the serving-dominant wide-vocabulary slabs.
+
+    The pass is named ``msq_qgram_filter_<layout>``, so the device trace
+    and the HLO module read ``jit_msq_qgram_filter_<layout>``.
     """
     import jax
     import jax.numpy as jnp
@@ -195,17 +198,22 @@ def _bounds_multi_jit(layout: str = "dense"):
     else:
         raise ValueError(f"unknown slab layout {layout!r}")
 
+    multi.__name__ = multi.__qualname__ = f"msq_qgram_filter_{layout}"
     return jax.jit(multi)
 
 
 @functools.lru_cache(maxsize=1)
 def _assign_lb_jit():
     """jit'd (Q, N) assignment-LB pass (the jax backend's stage 1.5) —
-    the reference body under jit, on shape-bucketed operands."""
+    the reference body under jit, on shape-bucketed operands, named
+    ``msq_assign_lb`` (``jit_msq_assign_lb`` in the device trace)."""
     import jax
 
     from repro.kernels.assign_lb.ref import batched_assign_lb_ref
-    return jax.jit(batched_assign_lb_ref)
+
+    def msq_assign_lb(*args):
+        return batched_assign_lb_ref(*args)
+    return jax.jit(msq_assign_lb)
 
 
 def sparse_query_fd(qfd: np.ndarray, pad: int = 16
@@ -427,28 +435,31 @@ class BatchedFilterEval:
             dev = self.device_cache.get_or_build(
                 key, "lb_db",
                 lambda: dist.put_sharded(self.mesh, host_db, db_specs))
-            qargs = dist.put_sharded(self.mesh, host_q, q_specs)
         else:
             dev = self.device_cache.get_or_build(
                 key, "lb_db",
                 lambda: tuple(jnp.asarray(x) for x in host_db))
-            qargs = tuple(jnp.asarray(x) for x in host_q)
-        if self.backend == "pallas":
-            qb_t, bb_t = self.lb_tile_table.lookup(
-                qvp.shape[0], np_, qvp.shape[1], sub.bvlab.shape[1])
-            out = aops.assign_lb_bounds_batched(*qargs, *dev,
-                                                qb=qb_t, bb=bb_t)
-        elif self.backend == "distributed":
-            from repro.core import jax_compat as jc
-            if self._lb_dist_fn is None:
-                from repro.core import distributed as dist
-                self._lb_dist_fn = dist.make_sharded_assign_lb(
-                    self.mesh, self._batch_axes)
-            with jc.set_mesh(self.mesh):
-                out = self._lb_dist_fn(*qargs, *dev)
-        else:
-            out = _assign_lb_jit()(*qargs, *dev)
-        return np.asarray(out)[:Q, :N]
+        # query upload, dispatch, device pass and copy back
+        with ambient_span("lb_device", n_queries=Q, n_graphs=N):
+            if self.backend == "distributed":
+                qargs = dist.put_sharded(self.mesh, host_q, q_specs)
+            else:
+                qargs = tuple(jnp.asarray(x) for x in host_q)
+            if self.backend == "pallas":
+                qb_t, bb_t = self.lb_tile_table.lookup(
+                    qvp.shape[0], np_, qvp.shape[1], sub.bvlab.shape[1])
+                out = aops.assign_lb_bounds_batched(*qargs, *dev,
+                                                    qb=qb_t, bb=bb_t)
+            elif self.backend == "distributed":
+                from repro.core import jax_compat as jc
+                if self._lb_dist_fn is None:
+                    self._lb_dist_fn = dist.make_sharded_assign_lb(
+                        self.mesh, self._batch_axes)
+                with jc.set_mesh(self.mesh):
+                    out = self._lb_dist_fn(*qargs, *dev)
+            else:
+                out = _assign_lb_jit()(*qargs, *dev)
+            return np.asarray(out)[:Q, :N]
 
     # ---- distributed slab-shard bookkeeping -------------------------------
     def _init_distributed(self, mesh, layout: str, k: int,
@@ -612,11 +623,13 @@ class BatchedFilterEval:
         else:
             bounds = self.bounds(idx, qs)
         out: List[Tuple[List[int], np.ndarray]] = []
-        for row in range(len(qs)):
-            keep = bounds[row] <= int(taus[row])
-            # idx is ascending (flatnonzero), so the kept ids stay sorted
-            out.append(([int(g) for g in idx[keep]],
-                        np.asarray(bounds[row][keep])))
+        with ambient_span("filter_select", n_queries=len(qs),
+                          n_graphs=int(len(idx))):
+            for row in range(len(qs)):
+                keep = bounds[row] <= int(taus[row])
+                # idx ascends (flatnonzero), so the kept ids stay sorted
+                out.append(([int(g) for g in idx[keep]],
+                            np.asarray(bounds[row][keep])))
         return out
 
     def _bounds_jax(self, idx: np.ndarray,
@@ -636,27 +649,25 @@ class BatchedFilterEval:
         if lay == "hot":
             cdt = sub.tail_minsum_batch(qb.fd).astype(np.int32)
             qb = qb._replace(fd=qb.fd[:, :sub.hot_d])
-            qids, qcnt = sparse_query_fd(qb.fd)
-            out = _bounds_multi_jit("hot")(
-                db, QueryArrays(*[jnp.asarray(x) for x in qb]),
-                jnp.asarray(cdt), jnp.asarray(qids), jnp.asarray(qcnt))
         elif lay == "packed":
             words, sb, widths = self.device_cache.get_or_build(
                 key, "jax_packed",
                 lambda: tuple(jnp.asarray(x) for x in
                               (sub.packed.words, sub.packed.sb,
                                sub.packed.widths)))
-            qids, qcnt = sparse_query_fd(qb.fd)
-            out = _bounds_multi_jit("packed")(
-                words, sb, widths, db,
-                QueryArrays(*[jnp.asarray(x) for x in qb]),
-                jnp.asarray(qids), jnp.asarray(qcnt))
-        else:
-            qids, qcnt = sparse_query_fd(qb.fd)
-            out = _bounds_multi_jit("dense")(
-                db, QueryArrays(*[jnp.asarray(x) for x in qb]),
-                jnp.asarray(qids), jnp.asarray(qcnt))
-        return np.asarray(out)[:Q, :N]
+        qids, qcnt = sparse_query_fd(qb.fd)
+        # query upload, dispatch, device pass and copy back
+        with ambient_span("filter_device", n_queries=Q, n_graphs=N):
+            qarr = QueryArrays(*[jnp.asarray(x) for x in qb])
+            qsparse = (jnp.asarray(qids), jnp.asarray(qcnt))
+            fn = _bounds_multi_jit(lay)
+            if lay == "hot":
+                out = fn(db, qarr, jnp.asarray(cdt), *qsparse)
+            elif lay == "packed":
+                out = fn(words, sb, widths, db, qarr, *qsparse)
+            else:
+                out = fn(db, qarr, *qsparse)
+            return np.asarray(out)[:Q, :N]
 
     def _bounds_np(self, idx: np.ndarray,
                    qs: Sequence[QueryArrays]) -> np.ndarray:
@@ -723,12 +734,11 @@ class BatchedFilterEval:
         p = self.partition
         sc = ops.make_scalars_batch(qs, p.x0, p.y0, p.l)
         qb_t, bb_t, bu_t = self.tile_table.lookup(Q, np_, fd_dev.shape[1])
-        with device_annotation("msq.qgram_filter.pallas"):
-            b, _ = ops.fused_filter_bounds_batched(
-                jnp.asarray(sc), fd_dev, jnp.asarray(qb.fd),
-                vhist_d, jnp.asarray(qb.vhist), ehist_d, jnp.asarray(qb.ehist),
-                degseq_d, jnp.asarray(qb.sigma), aux_d, cdt,
-                qb=qb_t, bb=bb_t, bu=bu_t)
+        b, _ = ops.fused_filter_bounds_batched(
+            jnp.asarray(sc), fd_dev, jnp.asarray(qb.fd),
+            vhist_d, jnp.asarray(qb.vhist), ehist_d, jnp.asarray(qb.ehist),
+            degseq_d, jnp.asarray(qb.sigma), aux_d, cdt,
+            qb=qb_t, bb=bb_t, bu=bu_t)
         return np.asarray(b)[:Q, :N]
 
     # ---- the distributed per-bucket step ----------------------------------
@@ -866,22 +876,26 @@ def batched_flat_candidates(ev: BatchedFilterEval, graphs: Sequence[Graph],
                               None if qtuples is None else qtuples[qi])
               for qi in qis]
         t_f = time.perf_counter() if spans_on else 0.0
+        c_f = time.thread_time() if spans_on else 0.0
         cands = ev.bucket_candidates(idx, qs, [int(taus[qi]) for qi in qis])
         if spans_on:
             obs.spans.record("filter_bucket", t_f, time.perf_counter(),
                              n_queries=len(qis), n_graphs=int(len(idx)),
-                             backend=ev.backend)
+                             backend=ev.backend,
+                             cpu_ms=1e3 * (time.thread_time() - c_f))
         for row, qi in enumerate(qis):
             ids[qi], bnds[qi] = cands[row]
         if lbs is not None:
             t0 = time.perf_counter()
+            c0 = time.thread_time() if spans_on else 0.0
             blbs = ev.bucket_assign_lbs([graphs[qi] for qi in qis],
                                         [cands[row][0]
                                          for row in range(len(qis))])
             t1 = time.perf_counter()
             if spans_on:
                 obs.spans.record("assign_lb", t0, t1, n_queries=len(qis),
-                                 n_pairs=sum(len(c[0]) for c in cands))
+                                 n_pairs=sum(len(c[0]) for c in cands),
+                                 cpu_ms=1e3 * (time.thread_time() - c0))
             share = (t1 - t0) / len(qis)
             for row, qi in enumerate(qis):
                 lbs[qi] = blbs[row]

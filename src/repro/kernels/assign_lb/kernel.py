@@ -125,4 +125,5 @@ def assign_lb_call(qv, qd, qeh, qn, dv, dd, deh, dn, *, qb: int = 8,
         scratch_shapes=[pltpu.VMEM((qb, bb, VM), jnp.int32),
                         pltpu.VMEM((qb, bb), jnp.int32)],
         interpret=interpret,
+        name="msq_assign_lb",
     )(scalars, qv, qd, qeh_lm, dv, dd, deh_planes, dn2)

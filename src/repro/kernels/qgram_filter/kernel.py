@@ -243,15 +243,18 @@ def _batched_kernel(scalars_ref,      # SMEM (QB, N_SCALARS) int32
         mask_ref[...] = (in_region & (bound <= tau)).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("qb", "bb", "bu", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("qb", "bb", "bu", "interpret", "name"))
 def fused_batched_call(scalars, fd, qfd, vhist, qvh, ehist, qeh, degseq,
                        qsig, aux, cdt, *, qb: int = 8, bb: int = 128,
-                       bu: int = 512, interpret: bool = False):
+                       bu: int = 512, interpret: bool = False,
+                       name: str = "msq_qgram_filter_dense"):
     """Raw query-batched pallas_call; shapes must already be tile-aligned.
 
     scalars (Q, N_SCALARS); fd (B, U); qfd (Q, U); vhist (B, NV);
     qvh (Q, NV); ehist (B, NE); qeh (Q, NE); degseq (B, VM); qsig (Q, VM);
     aux (B, 4); cdt (Q, B).  Returns ((Q, B) bounds, (Q, B) mask).
+    ``name`` is the kernel's name in the device trace.
     """
     Q, B, U = scalars.shape[0], fd.shape[0], fd.shape[1]
     NV = vhist.shape[1]
@@ -286,4 +289,5 @@ def fused_batched_call(scalars, fd, qfd, vhist, qvh, ehist, qeh, degseq,
         ],
         scratch_shapes=[pltpu.VMEM((qb, bb), jnp.int32)],
         interpret=interpret,
+        name=name,
     )(scalars, fd, qfd, vhist, qvh, ehist, qeh, degseq, qsig, aux, cdt)

@@ -145,7 +145,9 @@ def fused_filter_bounds_batched(scalars, fd, qfd, vhist, qvh, ehist, qeh,
         cdt_p = _pad_to(_pad_to(cdt.astype(jnp.int32), q_pad, 0), b_pad, 1)
     bounds, mask = fused_batched_call(
         sc_p, fd_p, qfd_p, vhist_p, qvh_p, ehist_p, qeh_p, degseq_p,
-        qsig_p, aux_p, cdt_p, qb=qb, bb=bb, bu=bu, interpret=interpret)
+        qsig_p, aux_p, cdt_p, qb=qb, bb=bb, bu=bu, interpret=interpret,
+        name="msq_qgram_filter_dense" if cdt is None
+        else "msq_qgram_filter_hot")
     return bounds[:Q, :B], mask[:Q, :B]
 
 

@@ -76,14 +76,20 @@ class SpanRecorder:
 
     @contextmanager
     def span(self, name: str, *, qid: Optional[int] = None, **args):
-        """Context-manager sugar for stages without pre-taken timestamps."""
+        """Context-manager sugar for stages without pre-taken timestamps.
+        Yields the span's args (the body may add to them) and records the
+        thread's CPU time over the interval as ``cpu_ms``: wall time
+        minus ``cpu_ms`` is time the thread waited (for the interpreter
+        lock, the device or the OS)."""
         if not self.enabled:
-            yield
+            yield args
             return
         t0 = time.perf_counter()
+        c0 = time.thread_time()
         try:
-            yield
+            yield args
         finally:
+            args["cpu_ms"] = 1e3 * (time.thread_time() - c0)
             self.record(name, t0, time.perf_counter(), qid=qid, **args)
 
     def extend(self, spans) -> None:

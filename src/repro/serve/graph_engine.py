@@ -373,7 +373,6 @@ class VerifyScheduler:
                  "pool_fallbacks", "pool_rebuilds", "error_pairs")
 
     def __init__(self, db, slice_expansions: Optional[int] = None,
-                 interval_sink: Optional[List[Tuple[float, float]]] = None,
                  executor: str = "inline", workers: int = 1,
                  obs: Optional[Observability] = None, faults=None,
                  dispatch_retries: int = 2, max_pool_rebuilds: int = 2):
@@ -413,7 +412,6 @@ class VerifyScheduler:
         self._heap: list = []       # guarded_by: self._cv
         self._inflight = 0          # guarded_by: self._cv
         self._closed = False        # guarded_by: self._cv
-        self._interval_sink = interval_sink
         # a registry view, not a dict (DESIGN.md §17): same keys and
         # mutation idiom, but snapshot/merge-able with every other
         # component.  Mutations stay under self._cv as before — the view
@@ -662,6 +660,9 @@ class VerifyScheduler:
         finish = True
         try:
             t0 = time.perf_counter()
+            obs = self.obs
+            spans_on = obs is not None and obs.spans.enabled
+            c0 = time.thread_time() if spans_on else 0.0
             if job.deadline is not None and t0 >= job.deadline:
                 with self._cv:
                     job.unverified += 1
@@ -692,19 +693,17 @@ class VerifyScheduler:
                 self.faults.fire("verify.slice", qid=job.qid, gid=int(gid))
             d, search = self._execute(search, job.deadline, qid=job.qid)
             t1 = time.perf_counter()
-            obs = self.obs
-            if obs is not None and obs.spans.enabled:
+            if spans_on:
                 # per-slice verify span: which pair, at what seed bound,
-                # how much A* it burned, and whether it decided (§17)
+                # how much A* it burned, whether it decided, and how much
+                # of the slice was this thread's CPU time (§17)
                 obs.spans.record(
                     "verify", t0, t1, qid=job.qid, gid=int(gid),
                     bound=int(bound), expansions=search.expansions - exp0,
-                    decided=d is not None)
-            self.metrics.observe("sched.verify_slice_s", t1 - t0)
+                    decided=d is not None,
+                    cpu_ms=1e3 * (time.thread_time() - c0))
             with self._cv:
                 job.verify_s += t1 - t0
-                if self._interval_sink is not None:
-                    self._interval_sink.append((t0, t1))
             if d is None:
                 if job.deadline is not None and t1 >= job.deadline:
                     with self._cv:

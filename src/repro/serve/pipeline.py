@@ -211,9 +211,6 @@ class AsyncGraphQueryEngine:
       undecided searches re-queue at their improved frontier bound.
     * ``default_deadline_s``: verification deadline applied to requests
       that don't carry their own ``deadline_s``.
-    * ``record_intervals``: collect per-stage (start, end) busy spans in
-      ``filter_intervals`` / ``verify_intervals`` for overlap accounting
-      (``benchmarks/query_throughput.py --pipeline``).
     * ``inbox_limit`` / ``inbox_bytes``: admission control (DESIGN.md
       §18) — the inbox is bounded by queued tickets and/or estimated
       bytes; an arrival past either bound triggers ``shed_policy``:
@@ -232,7 +229,7 @@ class AsyncGraphQueryEngine:
                  verify_executor: str = "thread",
                  slice_expansions: Optional[int] = None,
                  default_deadline_s: Optional[float] = None,
-                 record_intervals: bool = False, name: str = "apipe",
+                 name: str = "apipe",
                  inbox_limit: Optional[int] = None,
                  inbox_bytes: Optional[int] = None,
                  shed_policy: str = "reject",
@@ -253,18 +250,14 @@ class AsyncGraphQueryEngine:
         # the filter evaluator, the scheduler to the verify points
         self.faults = faults if faults is not None else engine.faults
         engine.faults = self.faults
-        self.filter_intervals: List[Tuple[float, float]] = []
-        self.verify_intervals: List[Tuple[float, float]] = []
         self.obs = engine.obs           # one ring/registry per pipeline
         self.scheduler = VerifyScheduler(
             engine.source.db, slice_expansions=slice_expansions,
-            interval_sink=self.verify_intervals if record_intervals else None,
             # map the thread alias; anything unknown reaches the
             # scheduler's own validation instead of silently degrading
             executor={"thread": "inline"}.get(verify_executor,
                                               verify_executor),
             workers=num_workers, obs=engine.obs, faults=self.faults)
-        self._record_intervals = record_intervals
         self._cv = threading.Condition()
         self._inbox: "deque[Tuple[float, QueryTicket]]" = \
             deque()                 # guarded_by: self._cv
@@ -576,8 +569,7 @@ class AsyncGraphQueryEngine:
         if spans_on:
             eng.obs.spans.record("filter", t0, t1, rows=len(rows),
                                  backend=eng.backend)
-        if self._record_intervals:
-            self.filter_intervals.append((t0, t1))
+            c1 = time.thread_time()
 
         n_db = len(eng.source.db)
         per_q_filter = (t1 - t0) / len(rows)
@@ -636,6 +628,11 @@ class AsyncGraphQueryEngine:
                 token=(ticket, key, r, cand, n_db, per_q_filter, lb_share),
                 on_match=self._on_match, on_done=self._on_done,
                 n_lb_pruned=n_pr, n_lb_tightened=n_tt, qid=ticket._qid)
+        if spans_on:
+            # the host work between the batch's filter and its worklist
+            eng.obs.spans.record("enqueue", t1, time.perf_counter(),
+                                 rows=len(rows),
+                                 cpu_ms=1e3 * (time.thread_time() - c1))
 
     # ---- stage: top-k escalation (runs on verifier threads) ----------------
     def _reenter(self, ticket: QueryTicket) -> None:

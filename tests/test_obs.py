@@ -272,3 +272,133 @@ def test_process_pool_astar_slice_spans(small_db):
         frags = [s for s in obs.spans.spans() if s.name == "astar_slice"]
         assert frags, "no worker span fragments crossed the pool"
         assert all(s.tid.startswith("ged-pool-") for s in frags)
+
+
+# ---- spans inside the filter, LB and verify layers -------------------------
+
+def _jax_engine(flat, spans=True):
+    return GraphQueryEngine(flat, backend="jax", result_cache_size=0,
+                            obs=Observability(spans=spans))
+
+
+def _run_async(eng, reqs):
+    from repro.serve.pipeline import AsyncGraphQueryEngine
+    with AsyncGraphQueryEngine(eng, max_batch=4, num_workers=2) as apipe:
+        out = [t.result(timeout=300) for t in apipe.submit_many(reqs)]
+        stats = apipe.stats
+    return out, stats
+
+
+def test_child_spans_nest_in_their_layer_spans(small_db, flat):
+    reqs = _requests(small_db, 8, seed=21, verify=True)
+    eng = _jax_engine(flat)
+    _run_async(eng, reqs)
+    spans = eng.obs.spans.spans()
+    names = {s.name for s in spans}
+    assert {"slab_gather", "slab_upload", "filter_device", "filter_select",
+            "lb_device", "enqueue", "verify"} <= names
+    parents = {"filter_device": {"filter_bucket"},
+               "filter_select": {"filter_bucket"},
+               "lb_device": {"assign_lb"},
+               "slab_gather": {"filter_bucket", "assign_lb"},
+               "slab_upload": {"filter_bucket", "assign_lb"}}
+    for s in spans:
+        if s.name not in parents:
+            continue
+        assert any(p.name in parents[s.name] and p.tid == s.tid
+                   and p.t0 <= s.t0 and s.t1 <= p.t1 for p in spans), \
+            f"{s.name} span lies in no {parents[s.name]} span of its thread"
+    # enqueue picks up where its batch's filter span ends
+    filters = {(s.tid, s.t1) for s in spans if s.name == "filter"}
+    for s in spans:
+        if s.name == "enqueue":
+            assert (s.tid, s.t0) in filters
+    for s in spans:
+        if s.name in ("slab_gather", "slab_upload"):
+            assert s.args["rows"] > 0 and s.args["bytes"] > 0
+            assert (s.args["field"] == "sub") == (s.name == "slab_gather")
+    timed = [s for s in spans if "cpu_ms" in s.args]
+    assert {s.name for s in timed} >= {
+        "filter_bucket", "assign_lb", "verify", "slab_gather", "slab_upload",
+        "filter_device", "filter_select", "lb_device", "enqueue"}
+    for s in timed:
+        assert 0.0 <= s.args["cpu_ms"] <= 1e3 * s.dur + 1.0, s
+
+
+def test_slab_cache_counters_reach_async_stats(small_db):
+    fresh = FlatMSQIndex(small_db)
+    ev = fresh.filter_eval("jax")
+    ev.device_cache.max_entries = 2        # small enough to evict
+    reqs = _requests(small_db, 8, seed=22, verify=False)
+    eng = _jax_engine(fresh, spans=False)
+    _run_async(eng, reqs)
+    _, stats = _run_async(eng, reqs)       # the same buckets again
+    cache = ev.device_cache.snapshot()
+    fields = {k.split(".")[1] for k in stats if k.startswith("slab_cache.")
+              and k.count(".") == 2}
+    assert {"sub", "jax_db", "lb_db"} <= fields
+    for kind in ("hits", "misses"):
+        assert sum(stats.get(f"slab_cache.{f}.{kind}", 0)
+                   for f in fields) == cache[kind]
+    assert cache["misses"] > 0 and cache["hits"] > 0
+    assert stats["slab_cache.evictions"] == cache["evictions"] > 0
+    assert stats["slab_cache.upload_bytes"] > 0
+
+
+def test_compile_span_and_counter_under_use_obs():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.obs import use_obs
+    obs = Observability(spans=True)
+    with use_obs(obs):
+        jax.jit(lambda x: x * 7 + 3)(jnp.ones(37, jnp.int32))
+    assert obs.metrics.counter_get("engine.compiles", 0) >= 1
+    comp = [s for s in obs.spans.spans() if s.name == "compile"]
+    assert comp and all(s.dur >= 0 for s in comp)
+    outside = Observability(spans=True)
+    jax.jit(lambda x: x * 11 + 5)(jnp.ones(41, jnp.int32))
+    assert len(outside.spans) == 0   # only the ambient obs is charged
+
+
+def test_jitted_passes_carry_stable_names(small_db, flat):
+    import jax.numpy as jnp
+
+    from repro.core.arrays import DBArrays, QueryArrays
+    from repro.core.engine import (_assign_lb_jit, _bounds_multi_jit,
+                                   sparse_query_fd)
+    from repro.core.slab import branch_features
+    from repro.kernels.assign_lb import ops as aops
+    ev = flat.filter_eval("jax")
+    reqs = _requests(small_db, 8, seed=23)
+    sub = ev.slab.gather(np.arange(len(small_db)), 512)
+    qb = ev.stack_queries([ev.query_arrays(r.graph, r.tau) for r in reqs])
+    qids, qcnt = sparse_query_fd(qb.fd)
+    text = _bounds_multi_jit("dense").lower(
+        DBArrays(*[jnp.asarray(x) for x in sub.base_arrays()]),
+        QueryArrays(*[jnp.asarray(x) for x in qb]), jnp.asarray(qids),
+        jnp.asarray(qcnt)).as_text()
+    assert "msq_qgram_filter_dense" in text
+    hs = [r.graph for r in reqs]
+    qv, qd, qeh = branch_features(hs, small_db.n_elabels,
+                                  max(h.n for h in hs))
+    qn = np.asarray([h.n for h in hs], np.int32)
+    text = _assign_lb_jit().lower(
+        *aops.pad_query_block(qv, qd, qeh, qn),
+        sub.bvlab, sub.bdeg, sub.behist, sub.nv).as_text()
+    assert "msq_assign_lb" in text
+
+
+def test_spans_off_records_nothing_and_answers_match(small_db, flat):
+    reqs = _requests(small_db, 10, seed=24, verify=True)
+    off = _jax_engine(flat, spans=False)
+    on = _jax_engine(flat, spans=True)
+    out_off, stats_off = _run_async(off, reqs)
+    out_on, _ = _run_async(on, reqs)
+    assert len(off.obs.spans) == 0 and off.obs.spans.dropped == 0
+    assert len(on.obs.spans) > 0
+    for a, b in zip(out_on, out_off):
+        assert a.candidates == b.candidates
+        assert a.matches == b.matches
+    # the cache counters are on either way
+    assert any(k.startswith("slab_cache.") for k in stats_off)

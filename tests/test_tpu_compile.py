@@ -114,3 +114,21 @@ def test_bitunpack_compiles_at_aids_widths(compile_tpu):
     compile_tpu(lambda sb, w, words: bitunpack_call(
         sb, w, words, n_blocks=n_blocks), (n_blocks,), (n_blocks,),
         (n_blocks * 128 + 128,))
+
+
+def test_pallas_kernels_carry_stable_names(compile_tpu):
+    """The served kernels keep their names on the chip, so a device trace
+    finds them: ``msq_qgram_filter_<layout>`` and ``msq_assign_lb``."""
+    from repro.kernels.assign_lb.ops import assign_lb_bounds_batched
+    from repro.kernels.qgram_filter.ops import fused_filter_bounds_batched
+    U = U_BY_LAYOUT["dense"]
+    hot = compile_tpu(lambda *a: fused_filter_bounds_batched(
+        *a, interpret=False), *_filter_shapes(Q, B, U))
+    dense = compile_tpu(lambda *a: fused_filter_bounds_batched(
+        *a, interpret=False), *_filter_shapes(Q, B, U)[:-1])
+    lb = compile_tpu(lambda *a: assign_lb_bounds_batched(
+        *a, interpret=False), (Q, VM), (Q, VM), (Q, VM, NE), (Q,), (B, VM),
+        (B, VM), (B, VM, NE), (B,))
+    assert "msq_qgram_filter_hot" in hot
+    assert "msq_qgram_filter_dense" in dense
+    assert "msq_assign_lb" in lb
