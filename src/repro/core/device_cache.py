@@ -1,21 +1,25 @@
-"""DeviceSlabCache: device-resident per-bucket slab operands
-(DESIGN.md §13).
+"""DeviceSlabCache: device-resident slab operands (DESIGN.md §13).
 
-Every filter backend gathers a bucket's rows out of the resident
-``FilterSlab`` and — for the jax / pallas / distributed paths — uploads
-the gathered operands to the device on *every* ``bounds`` call, even
-though the bucket → row-set mapping is fixed for the life of the slab.
-On a 5k-graph DB the dense F_D upload alone dwarfs the filter math.
+Two kinds of entry share one cache:
 
-This cache keys on the bucket identity (the gathered row indices plus the
-pad size) and holds, per bucket, the host-side gathered sub-slab and the
-backend-specific device-resident operands, so each is built/transferred
-once per (bucket, layout) and reused across batches.  Entries are
-LRU-bounded; query-side operands (small, per-batch) are never cached.
+* **Pinned** — the jax backend's filter keeps the evaluator's *whole*
+  ``FilterSlab`` on the device, uploaded once under one fixed key
+  (``("resident", layout)``: ``jax_db``, plus ``jax_packed`` for the
+  packed layout), and gathers each bucket's rows on the device inside
+  its jitted pass.  A pinned entry is never evicted, does not count
+  against ``max_entries``, and is dropped only by ``invalidate()``.
+* **LRU** — per-bucket entries keyed on the bucket identity (the
+  gathered row indices plus the pad size): the host-side gathered
+  sub-slab (``sub``) and the device operands built from it.  They serve
+  the assignment LB (``sub``, ``lb_db``), the pallas and distributed
+  filter paths, and the hot layout's host tail correction; each is built
+  once per (bucket, field) and reused across batches, LRU-bounded.
+  Query-side operands (small, per-batch) are never cached.
 
 Ownership: one cache per ``BatchedFilterEval``, created with its slab and
-dropped with it.  ``invalidate()`` empties the cache — called when the
-evaluator's slab is rebuilt (``BatchedFilterEval.rebuild_slab``) or when
+dropped with it.  ``invalidate()`` empties the cache, pinned entries
+included — called when the evaluator's slab is rebuilt
+(``BatchedFilterEval.rebuild_slab``) or when
 ``FlatMSQIndex.set_filter_eval`` replaces a registered evaluator, so a
 stale device copy can never outlive the slab it mirrors.
 
@@ -69,8 +73,9 @@ def bucket_key(idx: np.ndarray, n_pad: int) -> Tuple:
 
 
 class DeviceSlabCache:
-    """LRU cache of per-bucket gathered sub-slabs and their device
-    operands, shared by every backend path of one ``BatchedFilterEval``.
+    """Pinned whole-slab device copies plus an LRU cache of per-bucket
+    gathered sub-slabs and their device operands, shared by every backend
+    path of one ``BatchedFilterEval``.
     """
 
     def __init__(self, max_entries: int = 16):
@@ -78,6 +83,8 @@ class DeviceSlabCache:
         self._lock = threading.Lock()
         self._entries: "OrderedDict[Hashable, Dict[str, Any]]" = \
             OrderedDict()                       # guarded_by: self._lock
+        self._pinned: Dict[Hashable, Dict[str, Any]] = \
+            {}                                  # guarded_by: self._lock
         self.stats: Dict[str, int] = {          # guarded_by: self._lock
             "hits": 0, "misses": 0, "evictions": 0, "invalidations": 0}
         # duck-typed fault injector (serve.faults.FaultInjector); builds
@@ -90,35 +97,40 @@ class DeviceSlabCache:
 
     def __len__(self) -> int:
         with self._lock:
-            return len(self._entries)
+            return len(self._entries) + len(self._pinned)
 
     def snapshot(self) -> Dict[str, int]:
         """Consistent copy of the counters plus the current size —
         readers must not iterate ``stats`` while a builder commits."""
         with self._lock:
             out = dict(self.stats)
-            out["entries"] = len(self._entries)
+            out["entries"] = len(self._entries) + len(self._pinned)
             return out
 
     def items(self) -> List[Tuple[Hashable, str, Any]]:
         """``(key, field, value)`` for every cached field — a copy taken
         under the lock, for inspecting what is resident and where."""
         with self._lock:
-            return [(k, f, v) for k, entry in self._entries.items()
-                    for f, v in entry.items()]
+            return [(k, f, v)
+                    for table in (self._pinned, self._entries)
+                    for k, entry in table.items() for f, v in entry.items()]
 
     def get_or_build(self, key: Hashable, field: str,
-                     build: Callable[[], Any]) -> Any:
-        """Return the cached ``field`` of the ``key`` bucket, building it
+                     build: Callable[[], Any], *, pin: bool = False) -> Any:
+        """Return the cached ``field`` of the ``key`` entry, building it
         on first use.  Distinct fields of one bucket (host gather, jax
-        arrays, pallas operands, ...) share the entry and its LRU slot."""
+        arrays, pallas operands, ...) share the entry and its LRU slot;
+        ``pin`` keeps the entry out of the LRU until ``invalidate()``."""
         with self._lock:
-            entry = self._entries.get(key)
+            table = self._pinned if pin else self._entries
+            entry = table.get(key)
             hit = entry is not None and field in entry
             if hit:
-                self._entries.move_to_end(key)
+                if not pin:
+                    self._entries.move_to_end(key)
                 self.stats["hits"] += 1
                 value = entry[field]
+            generation = self.stats["invalidations"]
         if hit:
             ambient_count(f"engine.slab_cache.{field}.hits")
             return value
@@ -140,11 +152,16 @@ class DeviceSlabCache:
             args.update(rows=rows, bytes=nbytes)
         evicted = 0
         with self._lock:
-            entry = self._entries.setdefault(key, {})
-            self._entries.move_to_end(key)
-            # first writer wins so concurrent builders agree on the object
-            value = entry.setdefault(field, value)
             self.stats["misses"] += 1
+            # a build that raced an invalidation serves its caller but is
+            # not kept: it may mirror the slab that was just replaced
+            if self.stats["invalidations"] == generation:
+                table = self._pinned if pin else self._entries
+                entry = table.setdefault(key, {})
+                if not pin:
+                    self._entries.move_to_end(key)
+                # first writer wins: concurrent builders agree on it
+                value = entry.setdefault(field, value)
             while len(self._entries) > self.max_entries:
                 self._entries.popitem(last=False)
                 self.stats["evictions"] += 1
@@ -157,7 +174,9 @@ class DeviceSlabCache:
         return value
 
     def invalidate(self) -> None:
-        """Drop every entry (slab rebuilt / evaluator replaced)."""
+        """Drop every entry, pinned ones included (slab rebuilt /
+        evaluator replaced)."""
         with self._lock:
             self._entries.clear()
+            self._pinned.clear()
             self.stats["invalidations"] += 1

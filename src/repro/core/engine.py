@@ -148,12 +148,24 @@ def _bounds_multi_jit(layout: str = "dense"):
     """jit'd (Q, N) filter pass per slab layout: vmap of the single-query
     cascade, with the layout's C_D construction fused in (DESIGN.md §11).
 
+    The database operands are the evaluator's whole device-resident slab
+    (with its sentinel row, ``FilterSlab.base_arrays(sentinel=True)``),
+    passed as arguments — never closed over, which would bake the slab
+    into the executable as a constant — and ``rows`` picks the bucket:
+    (N,) int32 slab rows padded with the sentinel's index.  The pass
+    gathers the bucket on the device, so the padded shapes, and with
+    them the executables, are those of a per-bucket operand.
+
     C_D is evaluated *query-sparse* (DESIGN.md §13): a query graph touches
     a few dozen degree-q-gram ids, and ``min(F_D[:, j], 0) = 0`` for every
     column the query misses, so the min-sum gathers only the query's
     nonzero columns (``qids``/``qcnt``, zero-padded — pad slots contribute
     ``min(fd, 0) = 0``).  Bit-identical to the dense sweep, ~U/K times
-    less work on the serving-dominant wide-vocabulary slabs.
+    less work on the serving-dominant wide-vocabulary slabs.  Dense and
+    hot F_D are gathered by row first (contiguous rows, one (N, U) block
+    on the device) and then by the query's columns: indexing rows and
+    columns in one gather reads fewer entries but lowers to a scalar
+    gather per entry.
 
     The pass is named ``msq_qgram_filter_<layout>``, so the device trace
     and the HLO module read ``jit_msq_qgram_filter_<layout>``.
@@ -163,37 +175,49 @@ def _bounds_multi_jit(layout: str = "dense"):
 
     from repro.core import filters_jax as fj
 
-    def sparse_cd(fd, ids, cnt):
-        return jnp.minimum(fd[:, ids], cnt[None, :]).astype(
-            jnp.int32).sum(axis=1)
+    def bucket(db: DBArrays, rows) -> DBArrays:
+        # the bucket's rows of every per-graph operand, F_D included
+        return DBArrays(*(jnp.take(x, rows, axis=0) for x in db))
+
+    def sparse_cd(fdq, cnt):
+        return jnp.minimum(fdq, cnt[None, :]).astype(jnp.int32).sum(axis=1)
 
     if layout == "dense":
-        def multi(db: DBArrays, qb: QueryArrays, qids, qcnt) -> "jax.Array":
+        def multi(db: DBArrays, rows, qb: QueryArrays, qids,
+                  qcnt) -> "jax.Array":
+            sub = bucket(db, rows)
+
             def one(q, ids, cnt):
-                return fj.batched_bounds(db, q, c_d=sparse_cd(db.fd, ids,
-                                                              cnt))
+                return fj.batched_bounds(sub, q,
+                                         c_d=sparse_cd(sub.fd[:, ids], cnt))
             return jax.vmap(one)(qb, qids, qcnt)
     elif layout == "hot":
-        # db.fd is the (N, H) hot prefix, qids/qcnt the query's nonzero
+        # db.fd is the (B + 1, H) hot prefix, qids/qcnt the query's nonzero
         # entries within it, and cdt the host-computed (Q, N) CSR tail
         # correction — added to C_D before thresholding so the bound
         # stays admissible (DESIGN.md §3)
-        def multi(db: DBArrays, qb: QueryArrays, cdt, qids,
-                  qcnt) -> "jax.Array":
-            def one(q, t, ids, cnt):
-                return fj.batched_bounds(db, q,
-                                         c_d=sparse_cd(db.fd, ids, cnt) + t)
-            return jax.vmap(one)(qb, cdt, qids, qcnt)
+        def multi(db: DBArrays, rows, qb: QueryArrays, qids, qcnt,
+                  cdt) -> "jax.Array":
+            sub = bucket(db, rows)
+
+            def one(q, ids, cnt, t):
+                return fj.batched_bounds(
+                    sub, q, c_d=sparse_cd(sub.fd[:, ids], cnt) + t)
+            return jax.vmap(one)(qb, qids, qcnt, cdt)
     elif layout == "packed":
-        # the resident slab is the packed form; decode on device, then the
-        # usual cascade.  db.fd is a (N, 1) placeholder — C_D is supplied.
-        def multi(words, sb, widths, db: DBArrays, qb: QueryArrays,
+        # the resident slab is the packed form; gather the bucket's packed
+        # rows, decode them on device, then the usual cascade.  db.fd is a
+        # (B + 1, 1) placeholder — C_D is supplied.
+        def multi(words, sb, widths, db: DBArrays, rows, qb: QueryArrays,
                   qids, qcnt) -> "jax.Array":
             from repro.kernels.bitunpack.ref import unpack_rows_ref
-            fd = unpack_rows_ref(words, sb, widths)
+            sub = bucket(db, rows)
+            fd = unpack_rows_ref(*(jnp.take(x, rows, axis=0)
+                                   for x in (words, sb, widths)))
 
             def one(q, ids, cnt):
-                return fj.batched_bounds(db, q, c_d=sparse_cd(fd, ids, cnt))
+                return fj.batched_bounds(sub, q,
+                                         c_d=sparse_cd(fd[:, ids], cnt))
             return jax.vmap(one)(qb, qids, qcnt)
     else:
         raise ValueError(f"unknown slab layout {layout!r}")
@@ -632,6 +656,27 @@ class BatchedFilterEval:
                             np.asarray(bounds[row][keep])))
         return out
 
+    def _resident_jax(self) -> Tuple[DBArrays, Optional[Tuple]]:
+        """The whole slab on the device, with its sentinel row: uploaded
+        once under one fixed key and pinned in the cache until the slab
+        is rebuilt or the evaluator replaced (DESIGN.md §13).  Returns
+        the DBArrays and, for the packed layout, (words, sb, widths)."""
+        import jax.numpy as jnp
+
+        key = ("resident", self.slab_layout)
+        slab = self.slab
+        db = self.device_cache.get_or_build(
+            key, "jax_db", lambda: DBArrays(*[
+                jnp.asarray(x) for x in slab.base_arrays(sentinel=True)]),
+            pin=True)
+        packed = None
+        if self.slab_layout == "packed":
+            packed = self.device_cache.get_or_build(
+                key, "jax_packed", lambda: tuple(
+                    jnp.asarray(x) for x in slab.packed_arrays()),
+                pin=True)
+        return db, packed
+
     def _bounds_jax(self, idx: np.ndarray,
                     qs: Sequence[QueryArrays]) -> np.ndarray:
         import jax.numpy as jnp
@@ -639,34 +684,30 @@ class BatchedFilterEval:
         Q, N = len(qs), len(idx)
         qp = _pad_to(Q, _Q_PAD)
         np_ = _pad_to(N, _N_PAD)
-        key, sub = self._gather_cached(idx, np_)
-        db = self.device_cache.get_or_build(
-            key, "jax_db",
-            lambda: DBArrays(*[jnp.asarray(x) for x in sub.base_arrays()]))
+        db, packed = self._resident_jax()
+        # the bucket's slab rows, padded with the sentinel row's index
+        rows = np.full(np_, self.slab.B, np.int32)
+        rows[:N] = idx
         qs = list(qs) + [qs[-1]] * (qp - Q)          # pad with a repeat
         qb = self.stack_queries(qs)
         lay = self.slab_layout
         if lay == "hot":
+            _, sub = self._gather_cached(idx, np_)
             cdt = sub.tail_minsum_batch(qb.fd).astype(np.int32)
             qb = qb._replace(fd=qb.fd[:, :sub.hot_d])
-        elif lay == "packed":
-            words, sb, widths = self.device_cache.get_or_build(
-                key, "jax_packed",
-                lambda: tuple(jnp.asarray(x) for x in
-                              (sub.packed.words, sub.packed.sb,
-                               sub.packed.widths)))
         qids, qcnt = sparse_query_fd(qb.fd)
-        # query upload, dispatch, device pass and copy back
+        # row and query upload, dispatch, device pass and copy back
         with ambient_span("filter_device", n_queries=Q, n_graphs=N):
-            qarr = QueryArrays(*[jnp.asarray(x) for x in qb])
-            qsparse = (jnp.asarray(qids), jnp.asarray(qcnt))
+            args = (db, jnp.asarray(rows),
+                    QueryArrays(*[jnp.asarray(x) for x in qb]),
+                    jnp.asarray(qids), jnp.asarray(qcnt))
             fn = _bounds_multi_jit(lay)
             if lay == "hot":
-                out = fn(db, qarr, jnp.asarray(cdt), *qsparse)
+                out = fn(*args, jnp.asarray(cdt))
             elif lay == "packed":
-                out = fn(words, sb, widths, db, qarr, *qsparse)
+                out = fn(*packed, *args)
             else:
-                out = fn(db, qarr, *qsparse)
+                out = fn(*args)
             return np.asarray(out)[:Q, :N]
 
     def _bounds_np(self, idx: np.ndarray,

@@ -90,6 +90,30 @@ def branch_features(graphs, n_elabels: int, vmax: int
     return vlab, deg, eh
 
 
+def _pad_rows(x: np.ndarray, pad: int, fill: int = 0) -> np.ndarray:
+    """``x`` with ``pad`` rows of ``fill`` appended along the graph axis."""
+    if not pad:
+        return x
+    return np.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1),
+                  constant_values=fill)
+
+
+def _pad_packed(words: np.ndarray, sb: np.ndarray, widths: np.ndarray,
+                pad: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Packed rows with ``pad`` rows appended that decode to zeros: zero
+    words at the narrowest width (4*w words per block, so the offsets fit
+    any real row width)."""
+    if not pad:
+        return words, sb, widths
+    from repro.kernels.bitunpack.ops import WIDTHS
+    KB = sb.shape[1]
+    w0 = WIDTHS[0]
+    zero_sb = (np.arange(KB, dtype=np.int32) * 4 * w0)[None, :]
+    return (np.vstack([words, np.zeros((pad, words.shape[1]), words.dtype)]),
+            np.vstack([sb, np.repeat(zero_sb, pad, axis=0)]),
+            np.vstack([widths, np.full((pad, KB), w0, widths.dtype)]))
+
+
 def _ragged_take(off: np.ndarray, ids: np.ndarray, cnt: np.ndarray,
                  rows: np.ndarray
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -207,11 +231,7 @@ class FilterSlab:
         pad = n_pad - len(idx)
 
         def take(x, fill=0):
-            sub = np.asarray(x)[idx]
-            if pad:
-                widths = [(0, pad)] + [(0, 0)] * (sub.ndim - 1)
-                sub = np.pad(sub, widths, constant_values=fill)
-            return sub
+            return _pad_rows(np.asarray(x)[idx], pad, fill)
 
         sub = replace(
             self,
@@ -233,22 +253,10 @@ class FilterSlab:
                     [t_off, np.full(pad, t_off[-1], np.int64)])
             sub.t_off, sub.t_ids, sub.t_cnt = t_off, t_ids, t_cnt
         if self.layout == "packed":
-            from repro.kernels.bitunpack.ops import WIDTHS, PackedRows
+            from repro.kernels.bitunpack.ops import PackedRows
             pk = self.packed
-            words = pk.words[idx]
-            sb = pk.sb[idx]
-            widths = pk.widths[idx]
-            if pad:
-                KB = sb.shape[1]
-                # a pad row decodes to zeros: zero words at the narrowest
-                # width (4*w words per block, so offsets fit any real W)
-                w0 = WIDTHS[0]
-                zero_sb = (np.arange(KB, dtype=np.int32) * 4 * w0)[None, :]
-                words = np.vstack(
-                    [words, np.zeros((pad, words.shape[1]), words.dtype)])
-                sb = np.vstack([sb, np.repeat(zero_sb, pad, axis=0)])
-                widths = np.vstack(
-                    [widths, np.full((pad, KB), w0, widths.dtype)])
+            words, sb, widths = _pad_packed(pk.words[idx], pk.sb[idx],
+                                            pk.widths[idx], pad)
             sub.packed = PackedRows(words=words, sb=sb, widths=widths,
                                     n_entries=pk.n_entries)
         return sub
@@ -260,17 +268,34 @@ class FilterSlab:
         return np.flatnonzero(m)
 
     # ---- device-side view -------------------------------------------------
-    def base_arrays(self) -> DBArrays:
+    def base_arrays(self, sentinel: bool = False) -> DBArrays:
         """The DBArrays a filter pass consumes.  ``fd`` is the layout's
         dense carrier: full matrix (dense), hot prefix (hot), or a (B, 1)
         placeholder (packed — the pass decodes ``self.packed`` itself and
-        supplies C_D explicitly)."""
+        supplies C_D explicitly).
+
+        ``sentinel`` appends one row at index ``B`` with the fills
+        ``gather`` pads a bucket with (never in-region, zero F_D, sizes
+        and histograms): the whole-slab copy a device pass gathers a
+        bucket from by row index, padding with ``B``."""
         fd = self.fd
         if fd is None:
             fd = np.zeros((self.B, 1), np.int32)
-        return DBArrays(nv=self.nv, ne=self.ne, degseq=self.degseq,
-                        vhist=self.vhist, ehist=self.ehist, fd=fd,
-                        region_i=self.region_i, region_j=self.region_j)
+        db = DBArrays(nv=self.nv, ne=self.ne, degseq=self.degseq,
+                      vhist=self.vhist, ehist=self.ehist, fd=fd,
+                      region_i=self.region_i, region_j=self.region_j)
+        if not sentinel:
+            return db
+        fills = {"region_i": _IMPOSSIBLE, "region_j": _IMPOSSIBLE}
+        return DBArrays(**{f: _pad_rows(x, 1, fills.get(f, 0))
+                           for f, x in db._asdict().items()})
+
+    def packed_arrays(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The packed layout's ``(words, sb, widths)`` with one sentinel
+        row appended that decodes to zeros, as ``base_arrays(sentinel=True)``
+        for the device pass to gather from."""
+        pk = self.packed
+        return _pad_packed(pk.words, pk.sb, pk.widths, 1)
 
     # ---- host C_D (numpy backend + overflow fallback) ---------------------
     def fd_dense_np(self) -> np.ndarray:

@@ -218,3 +218,126 @@ def test_engine_tile_table_plumbs_to_evaluator(flat, small_db):
                       small_db.n_elabels)
     eng.submit([GraphQuery(g, 2, verify=False)])
     assert flat.filter_eval("pallas").tile_table is table
+
+
+# --------------------------------------------------------------------------
+# jax backend: the whole slab resident, buckets gathered on the device
+# --------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def mid_flat():
+    """More than 512 graphs, so a bucket can sit just above a pad step."""
+    return FlatMSQIndex(aids_like_db(530, seed=7))
+
+
+def _bucket(ev, kind, N, seed):
+    """Row indices of one bucket: a region bucket (non-contiguous, as
+    ``graphs_in_rect`` gives it), a random sorted subset, or all rows."""
+    B = ev.slab.B
+    if kind == "rect":
+        ri, rj = ev.slab.region_i, ev.slab.region_j
+        idx = ev.graphs_in_rect((int(ri.min()), int(np.median(ri)),
+                                 int(rj.min()), int(rj.max())))
+        assert 0 < len(idx) < B and np.any(np.diff(idx) > 1)
+        return idx
+    if N >= B:
+        return np.arange(B)
+    return np.sort(np.random.default_rng(seed).choice(B, N, replace=False))
+
+
+@pytest.mark.parametrize("slab", ["dense", "hot", "packed"])
+@pytest.mark.parametrize("Q,N,kind", [(1, 17, "subset"), (5, 140, "subset"),
+                                      (3, 0, "rect"), (9, 513, "subset"),
+                                      (11, 530, "all")])
+def test_jax_resident_matches_numpy(mid_flat, slab, Q, N, kind):
+    db = mid_flat.db
+    ev_np = mid_flat.filter_eval("numpy", slab=slab, hot_d=24)
+    ev_jx = mid_flat.filter_eval("jax", slab=slab, hot_d=24)
+    qs, taus = _queries(db, ev_np, Q, seed=Q * 1000 + N)
+    idx = _bucket(ev_np, kind, N, seed=N)
+    want = np.asarray(ev_np.bounds(idx, qs), np.int64)
+    got = np.asarray(ev_jx.bounds(idx, qs), np.int64)
+    assert got.shape == (Q, len(idx))
+    assert np.array_equal(got, want)
+    for (gi, gb), (wi, wb) in zip(ev_jx.bucket_candidates(idx, qs, taus),
+                                  ev_np.bucket_candidates(idx, qs, taus)):
+        assert gi == wi
+        assert np.array_equal(np.asarray(gb, np.int64),
+                              np.asarray(wb, np.int64))
+
+
+@pytest.mark.parametrize("slab", ["dense", "packed"])
+def test_jax_filter_uploads_the_slab_once(flat, small_db, slab):
+    ev = BatchedFilterEval(flat.db, flat.enc, flat.partition, "jax",
+                           slab=slab)
+    qs, _ = _queries(small_db, ev, 4, seed=11)
+    rng = np.random.default_rng(12)
+    for n in (5, 40, 77, 120, 139, 140):
+        ev.bounds(np.sort(rng.choice(len(small_db), n, replace=False)), qs)
+    fields = {f for _, f, _ in ev.device_cache.items()}
+    assert fields == ({"jax_db", "jax_packed"} if slab == "packed"
+                      else {"jax_db"})            # no host gather (sub)
+    assert ev.device_cache.stats["misses"] == len(fields)
+    assert ev.device_cache.stats["hits"] == 5 * len(fields)
+    (db,) = [v for _, f, v in ev.device_cache.items() if f == "jax_db"]
+    assert db.fd.shape[0] == len(small_db) + 1   # the sentinel row
+    assert int(db.region_i[-1]) == -(2 ** 20) and int(db.nv[-1]) == 0
+
+
+def test_resident_slab_pinned_until_invalidated(flat, small_db):
+    ev = BatchedFilterEval(flat.db, flat.enc, flat.partition, "jax",
+                           device_cache_entries=1)
+    qs, _ = _queries(small_db, ev, 3, seed=13)
+    graphs = [small_db[i] for i in range(3)]
+    rng = np.random.default_rng(14)
+    for n in (30, 60, 90):
+        idx = np.sort(rng.choice(len(small_db), n, replace=False))
+        ev.bounds(idx, qs)
+        # each LB call keys LRU entries of its own (sub, lb_db)
+        ev.bucket_assign_lbs(graphs, [[int(g) for g in idx[:k]]
+                                      for k in (2, 3, 4)])
+    snap = ev.device_cache.snapshot()
+    assert snap["evictions"] >= 2
+    assert snap["entries"] == 2                 # one LRU entry + the pin
+    keys = {k for k, _, _ in ev.device_cache.items()}
+    assert ("resident", "dense") in keys
+    assert sum(1 for _, f, _ in ev.device_cache.items()
+               if f == "jax_db") == 1
+    ev.device_cache.invalidate()
+    assert len(ev.device_cache) == 0
+    want = ev.bounds(np.arange(len(small_db)), qs)   # uploaded again
+    assert ("resident", "dense") in {k for k, _, _ in
+                                     ev.device_cache.items()}
+    ev.rebuild_slab(layout="hot", hot_d=16)
+    assert len(ev.device_cache) == 0
+    got = ev.bounds(np.arange(len(small_db)), qs)
+    assert ("resident", "hot") in {k for k, _, _ in ev.device_cache.items()}
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_same_padded_bucket_shape_builds_no_executable(flat, small_db):
+    from repro.obs import Observability, use_obs
+    ev = BatchedFilterEval(flat.db, flat.enc, flat.partition, "jax")
+    qs, _ = _queries(small_db, ev, 17, seed=15)  # a query pad (24) of its own
+    obs = Observability()
+    with use_obs(obs):
+        ev.bounds(np.arange(0, 100), qs)
+        built = obs.metrics.counter_get("engine.compiles", 0)
+        assert built >= 1
+        ev.bounds(np.arange(1, 140, 2), qs)     # another bucket, same pad
+        ev.bounds(np.arange(40, 140), qs)
+        assert obs.metrics.counter_get("engine.compiles", 0) == built
+
+
+def test_build_racing_invalidation_is_not_kept():
+    cache = DeviceSlabCache(max_entries=4)
+
+    def build():
+        cache.invalidate()          # the slab is replaced mid-build
+        return "stale"
+    assert cache.get_or_build(("resident", "dense"), "jax_db", build,
+                              pin=True) == "stale"
+    assert len(cache) == 0
+    assert cache.get_or_build(("resident", "dense"), "jax_db",
+                              lambda: "fresh", pin=True) == "fresh"
+    assert len(cache) == 1

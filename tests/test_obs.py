@@ -372,12 +372,15 @@ def test_jitted_passes_carry_stable_names(small_db, flat):
     ev = flat.filter_eval("jax")
     reqs = _requests(small_db, 8, seed=23)
     sub = ev.slab.gather(np.arange(len(small_db)), 512)
+    rows = np.full(512, len(small_db), np.int32)
+    rows[:len(small_db)] = np.arange(len(small_db))
     qb = ev.stack_queries([ev.query_arrays(r.graph, r.tau) for r in reqs])
     qids, qcnt = sparse_query_fd(qb.fd)
     text = _bounds_multi_jit("dense").lower(
-        DBArrays(*[jnp.asarray(x) for x in sub.base_arrays()]),
-        QueryArrays(*[jnp.asarray(x) for x in qb]), jnp.asarray(qids),
-        jnp.asarray(qcnt)).as_text()
+        DBArrays(*[jnp.asarray(x)
+                   for x in ev.slab.base_arrays(sentinel=True)]),
+        jnp.asarray(rows), QueryArrays(*[jnp.asarray(x) for x in qb]),
+        jnp.asarray(qids), jnp.asarray(qcnt)).as_text()
     assert "msq_qgram_filter_dense" in text
     hs = [r.graph for r in reqs]
     qv, qd, qeh = branch_features(hs, small_db.n_elabels,

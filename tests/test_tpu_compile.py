@@ -54,11 +54,13 @@ def compile_tpu(topo):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
 
-    def compile_(fn, *shapes):
-        args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-                for s in shapes]
+    def compile_(fn, *shapes, mosaic=True):
+        args = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip),
+            list(shapes), is_leaf=lambda s: isinstance(s, tuple) and all(
+                isinstance(d, int) for d in s))
         text = jax.jit(fn).lower(*args).compile().as_text()
-        assert "tpu_custom_call" in text
+        assert ("tpu_custom_call" in text) == mosaic
         return text
 
     yield compile_
@@ -132,3 +134,24 @@ def test_pallas_kernels_carry_stable_names(compile_tpu):
     assert "msq_qgram_filter_hot" in hot
     assert "msq_qgram_filter_dense" in dense
     assert "msq_assign_lb" in lb
+
+
+# (graphs, F_D width, vertex width, vertex / edge labels, padded bucket
+# rows, sparse query width) of the two served deployments
+SERVED = {"aids": (42687, 1851, 64, 62, 3, 5632, 64),
+          "s100k": (100000, 342, 16, 5, 2, 65536, 32)}
+
+
+@pytest.mark.parametrize("name", sorted(SERVED))
+def test_resident_filter_pass_compiles_at_served_sizes(compile_tpu, name):
+    """The jax backend's filter pass over the whole resident slab (plus
+    its sentinel row), gathering one padded bucket by row index."""
+    from repro.core.arrays import DBArrays, QueryArrays
+    from repro.core.engine import _bounds_multi_jit
+    b, u, vm, nv, ne, n, k = SERVED[name]
+    r = b + 1
+    db = DBArrays((r,), (r,), (r, vm), (r, nv), (r, ne), (r, u), (r,), (r,))
+    qb = QueryArrays((Q,), (Q,), (Q, vm), (Q, nv), (Q, ne), (Q, u), (Q,))
+    text = compile_tpu(_bounds_multi_jit("dense"), db, (n,), qb, (Q, k),
+                       (Q, k), mosaic=False)
+    assert "msq_qgram_filter_dense" in text
